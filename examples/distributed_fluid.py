@@ -1,46 +1,45 @@
-"""DistEGNN end-to-end: partition a fluid graph over 4 (emulated) devices,
-train with psum-synchronised virtual nodes, verify the distributed forward
-matches the single-device model exactly.
+"""DistEGNN end-to-end: partition a fluid graph over 4 devices, train with
+psum-synchronised virtual nodes, verify the distributed forward matches the
+single-device model exactly.
 
+On a four-chip host:
     PYTHONPATH=src python examples/distributed_fluid.py
-(re-executes itself with XLA_FLAGS to get 4 host devices)
+On the CPU, with four emulated devices:
+    XLA_FLAGS=--xla_force_host_platform_device_count=4 JAX_PLATFORMS=cpu \
+        PYTHONPATH=src python examples/distributed_fluid.py
 """
-import os
-import sys
+import jax
+import jax.numpy as jnp
+import numpy as np
 
-N_DEV = 4
-_WANT = f"--xla_force_host_platform_device_count={N_DEV}"
-if os.environ.get("XLA_FLAGS") != _WANT:
-    os.environ["XLA_FLAGS"] = _WANT
-    os.execv(sys.executable, [sys.executable] + sys.argv)
-
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-import numpy as np  # noqa: E402
-
-from repro.core.graph import make_graph  # noqa: E402
-from repro.data.fluid import generate_fluid_dataset  # noqa: E402
-from repro.data.partition import partition_sample  # noqa: E402
-from repro.distributed.dist_egnn import (build_dist_apply,  # noqa: E402
+from repro.core.graph import make_graph
+from repro.data.fluid import generate_fluid_dataset
+from repro.data.partition import partition_sample
+from repro.distributed.dist_egnn import (build_dist_apply,
                                          build_dist_train_step, make_gnn_mesh,
                                          stack_partitions)
-from repro.models.fast_egnn import (FastEGNNConfig, fast_egnn_apply,  # noqa: E402
+from repro.models.fast_egnn import (FastEGNNConfig, fast_egnn_apply,
                                     init_fast_egnn)
-from repro.training.optim import Adam  # noqa: E402
+from repro.training.optim import Adam
+
+N_DEV = 4
 
 
 def main():
     print(f"devices: {jax.devices()}")
+    if len(jax.devices()) < N_DEV:
+        raise SystemExit(f"needs {N_DEV} devices, JAX found "
+                         f"{len(jax.devices())} (see the module docstring)")
+    mesh = make_gnn_mesh(N_DEV)
     data = generate_fluid_dataset(8, n_particles=400)
     pgs = [[partition_sample(s.x0, s.v0, s.h, s.x1, d=N_DEV, r=0.05, seed=j)
             for j, s in enumerate(data[i : i + 4])] for i in (0, 4)]
-    batches = [stack_partitions(p) for p in pgs]
+    batches = [stack_partitions(p, mesh) for p in pgs]
     print(f"partitioned: {batches[0].x.shape} per-shard edges "
           f"{float(batches[0].edge_mask.sum(-1).mean()):.0f}")
 
     cfg = FastEGNNConfig(n_layers=3, hidden=32, h_in=1, n_virtual=3, s_dim=32)
     params = init_fast_egnn(jax.random.PRNGKey(0), cfg)
-    mesh = make_gnn_mesh(N_DEV)
 
     # 1. consistency: distributed == single-device on the same (union) graph
     x_pred, vs = build_dist_apply(cfg, mesh)(params, batches[0])

@@ -177,35 +177,65 @@ def dispatch_mode(counts: dict, use_kernel: bool, backend_mode: str) -> str:
 
 
 # Per-window VMEM budget of the banded-CSR tiling (DESIGN.md §3.2): the
-# kernel's working set is bounded by the window sizes, not by N, so
+# kernels' working set is bounded by the window sizes, not by N, so
 # eligibility is a budget on the per-step VMEM footprint — constant in
-# graph size.  12 MiB leaves headroom on a 16 MiB-VMEM TPU core for
-# Pallas' double-buffered pipelining of the edge/window streams.
-EDGE_KERNEL_VMEM_BUDGET = 12 * 2**20
+# graph size.  The budget is the scoped-VMEM limit the kernels are compiled
+# with (``edge_message.VMEM_LIMIT_BYTES``), and the footprint covers the
+# fused backward, whose two passes are the largest of the three kernels.
 EDGE_KERNEL_BLOCK_E = 128
 
 
-def edge_kernel_vmem_bytes(n_nodes: int, dh: int, h1: int, m: int,
-                           block_e: int = EDGE_KERNEL_BLOCK_E) -> int:
-    """Per-grid-step VMEM footprint model of the banded edge kernel.
+def _tile_bytes(rows: int, cols: int, itemsize: int) -> int:
+    """VMEM bytes of a (rows, cols) block: (8, 128) tiles of 32-bit words,
+    (16, 128) for 16-bit ones — a width-3 block occupies 128 lanes."""
+    sub = 8 * (4 // itemsize)
+    return (-(-rows // sub) * sub) * (-(-cols // 128) * 128) * itemsize
 
-    Counts the resident buffers of one step at the :func:`pick_windows`
-    band sizes: the two one-hots (block_e × swindow/window), the x/h
-    sender+receiver windows (×2 for the pipeline's double buffer), the
-    output blocks, and the (block_e, ·) edge intermediates.  Weights are
-    O(dh·h1) and counted once.  All terms are window-bounded — the model
-    is independent of N once the windows saturate their defaults.
+
+def edge_kernel_vmem_bytes(n_nodes: int, dh: int, h1: int, m: int,
+                           block_e: int = EDGE_KERNEL_BLOCK_E,
+                           precision: str = "f32") -> int:
+    """Per-grid-step VMEM footprint model of the fused edge kernels.
+
+    Largest of the forward and the two backward passes at the
+    :func:`pick_windows` band sizes, counting what each pass keeps in
+    VMEM: the (block_e, ·) edge streams and the receiver-window blocks
+    double-buffered, the sender-window blocks (single-buffered in the
+    backward), the weights in the compute dtype plus f32 weight-gradient
+    accumulators, the two one-hots, eight (block_e, hidden) f32 edge
+    temporaries, and — for f32 compute — the bf16 pieces of the one-hots
+    that full-f32 contraction splits them into.  Calibrated against the
+    v5e compiler (``tests/test_tpu_compile.py``): at the default windows
+    hidden 256 compiles and 384 (f32) / 512 (bf16) does not, and this
+    model puts the 16 MiB budget between them.  All terms are
+    window-bounded — the model is independent of N once the windows
+    saturate their defaults.
     """
     from repro.kernels.edge_message import pick_windows
+    from repro.kernels.runtime import resolve_precision
 
     window, swindow, _ = pick_windows(n_nodes)
-    f32 = 4
-    one_hots = block_e * (swindow + window) * f32
-    node_windows = 2 * (swindow + window) * (3 + dh) * f32  # double-buffered
-    out_blocks = window * (3 + m + 1) * f32
-    edge_tmp = block_e * (3 + 1 + 2 * h1 + 2 * m) * f32
-    weights = (2 * dh * h1 + 2 * h1 + h1 * m + 2 * m + m * h1) * f32
-    return one_hots + node_windows + out_blocks + edge_tmp + weights
+    c = resolve_precision(precision).compute_dtype.itemsize
+    f = 4
+    t = _tile_bytes
+    be, w, sw = block_e, window, swindow
+    weights = ((dh, h1), (dh, h1), (1, h1), (1, h1), (h1, m), (1, m),
+               (m, h1), (1, h1), (h1, 1))
+    w_c = sum(t(r, k, c) for r, k in weights)
+    w_f = sum(t(r, k, f) for r, k in weights)
+    edges = 2 * 3 * t(be, 1, 4)
+    one_hots = t(be, sw, c) + t(be, w, c)
+    if c == 4:
+        one_hots += 3 * (t(be, sw, 2) + t(be, w, 2))
+    edge_tmp = 8 * t(be, max(dh, h1, m), f)
+    common = edges + w_c + one_hots + edge_tmp
+    fwd = (common + 2 * (t(w, 3, c) + t(w, dh, c) + t(sw, 3, c) + t(sw, dh, c))
+           + 2 * (t(w, 3, f) + t(w, m, f) + t(w, 1, f)))
+    bwd_in = (common + 2 * (t(w, 3, f) + t(w, m, f) + t(w, 1, f) + t(w, 3, c)
+                            + t(w, dh, c)) + t(sw, 3, c) + t(sw, dh, c))
+    bwd_a = bwd_in + 2 * (t(w, 3, f) + t(w, dh, f)) + w_f
+    bwd_b = bwd_in + t(sw, 3, f) + t(sw, dh, f)
+    return max(fwd, bwd_a, bwd_b)
 
 
 def kernel_supported(lp: dict, g: GeometricGraph, spec: EdgeSpec) -> bool:
@@ -215,10 +245,13 @@ def kernel_supported(lp: dict, g: GeometricGraph, spec: EdgeSpec) -> bool:
     ``[h_i | h_j | d²]``, 2-layer (or identity) gate, masked mean
     reduction.  Graph size no longer gates dispatch — the banded-CSR
     tiling bounds VMEM by the node windows, so the check is a per-window
-    budget (:func:`edge_kernel_vmem_bytes`) that only unusually wide
-    hidden dims can exceed.  Anything else — extra edge attributes,
+    budget (:func:`edge_kernel_vmem_bytes`, forward and backward, against
+    the scoped-VMEM limit the kernels compile with) that only unusually
+    wide hidden dims can exceed.  Anything else — extra edge attributes,
     deeper MLPs, unnormalised sums — falls back to the jnp path.
     """
+    from repro.kernels.edge_message import VMEM_LIMIT_BYTES
+
     if spec.use_edge_attr and g.edge_attr.shape[-1] > 0:
         return False
     if not spec.normalize:
@@ -230,8 +263,9 @@ def kernel_supported(lp: dict, g: GeometricGraph, spec: EdgeSpec) -> bool:
     w1 = lp["phi1"][0]["w"]
     w2 = lp["phi1"][1]["w"]
     dh = g.feat_dim if spec.use_h else 1
-    vmem = edge_kernel_vmem_bytes(g.n_nodes, dh, w1.shape[1], w2.shape[1])
-    return vmem <= EDGE_KERNEL_VMEM_BUDGET
+    vmem = edge_kernel_vmem_bytes(g.n_nodes, dh, w1.shape[1], w2.shape[1],
+                                  precision=spec.precision)
+    return vmem <= VMEM_LIMIT_BYTES
 
 
 def edge_pathway(lp: dict, h: Array, x: Array, g: GeometricGraph,

@@ -27,8 +27,19 @@ def init_linear(key, d_in: int, d_out: int, bias: bool = True):
     return p
 
 
+def dot_precision(*operands):
+    """Precision for an XLA dot over ``operands``: ``HIGHEST`` when they
+    contract in f32, the backend default otherwise.  A TPU runs a
+    default-precision f32 dot as one bf16 pass (~3 significant digits), so
+    the model's own dots pin it, as ``kernels.edge_message._mm`` does
+    inside the kernels (DESIGN.md §9.3)."""
+    if jnp.result_type(*operands) == jnp.float32:
+        return jax.lax.Precision.HIGHEST
+    return None
+
+
 def linear(params, x: Array) -> Array:
-    y = x @ params["w"]
+    y = jnp.matmul(x, params["w"], precision=dot_precision(x, params["w"]))
     if "b" in params:
         y = y + params["b"]
     return y

@@ -24,7 +24,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.core.mlp import init_mlp, init_stacked_mlp, mlp
+from repro.core.mlp import dot_precision, init_mlp, init_stacked_mlp, mlp
 
 Array = jax.Array
 
@@ -54,7 +54,7 @@ def init_virtual_coords(x: Array, node_mask: Array, n_channels: int,
 def virtual_global_message(z: Array, com: Array) -> Array:
     """Eq. 4: E(3)-invariant Gram matrix of centred virtual coords, (C, C)."""
     zc = z - com[None, :]
-    return zc @ zc.T
+    return jnp.matmul(zc, zc.T, precision=dot_precision(zc))
 
 
 def init_virtual_block(key, n_channels: int, h_dim: int, s_dim: int, hidden: int,

@@ -42,33 +42,12 @@ from repro.training.optim import Adam
 Array = jax.Array
 GRAPH_AXIS = "graph"
 
-# jax 0.4.x ↔ 0.8.x compat: prefer the stable jax.shard_map API, falling
-# back to jax.experimental.shard_map; the replication-check kwarg is keyed
-# on the actual signature (0.5/0.6 expose jax.shard_map but still spell it
-# check_rep; 0.7+ renamed it to check_vma).
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:  # pragma: no cover - exercised on jax<0.5 only
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-import inspect as _inspect
-
-_SHARD_MAP_KW = (
-    {"check_vma": False}
-    if "check_vma" in _inspect.signature(_shard_map).parameters
-    else {"check_rep": False}
-)
-
-
 def make_gnn_mesh(n_devices: Optional[int] = None) -> Mesh:
     """1-D mesh over the graph-partition axis (data parallel handled by vmap
     inside each shard — every device owns shard d of *all* batch elements)."""
-    devs = jax.devices()
-    n = n_devices or len(devs)
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh((n,), (GRAPH_AXIS,),
-                             axis_types=(jax.sharding.AxisType.Auto,))
-    return jax.make_mesh((n,), (GRAPH_AXIS,))
+    n = n_devices or len(jax.devices())
+    return jax.make_mesh((n,), (GRAPH_AXIS,),
+                         axis_types=(jax.sharding.AxisType.Auto,))
 
 
 class ShardedBatch(NamedTuple):
@@ -143,16 +122,27 @@ def stack_partitions_host(pgs, layout_cache=None) -> dict:
             for f in ShardedBatch._fields}
 
 
-def sharded_batch_to_device(host: dict) -> ShardedBatch:
-    """Stacked numpy field dict → device ShardedBatch (async transfer)."""
-    return ShardedBatch(**{f: jnp.asarray(a) for f, a in host.items()})
+def sharded_batch_to_device(host: dict, mesh: Optional[Mesh] = None
+                            ) -> ShardedBatch:
+    """Stacked numpy field dict → device ShardedBatch (async transfer).
+
+    With a ``mesh`` each field is placed ``P('graph')``: shard d's rows go
+    straight to device d, so no device stages another's shard and the
+    jitted programs see the sharding they produce (no reshard, no retrace
+    between the first call and later ones).  Without one the fields land
+    on the default device (single-device reference callers)."""
+    if mesh is None:
+        return ShardedBatch(**{f: jnp.asarray(a) for f, a in host.items()})
+    sharding = NamedSharding(mesh, P(GRAPH_AXIS))
+    return ShardedBatch(**{f: jax.device_put(a, sharding)
+                           for f, a in host.items()})
 
 
-def stack_partitions(pgs) -> ShardedBatch:
+def stack_partitions(pgs, mesh: Optional[Mesh] = None) -> ShardedBatch:
     """list[PartitionedGraph] (one per batch element, each (D, ...)) →
-    ShardedBatch.  See :func:`stack_partitions_host` for the capacity
-    re-padding semantics."""
-    return sharded_batch_to_device(stack_partitions_host(pgs))
+    ShardedBatch, placed on ``mesh`` when given.  See
+    :func:`stack_partitions_host` for the capacity re-padding semantics."""
+    return sharded_batch_to_device(stack_partitions_host(pgs), mesh)
 
 
 def _local_graph(sb: ShardedBatch) -> GeometricGraph:
@@ -229,10 +219,10 @@ def build_dist_apply(cfg: FastEGNNConfig, mesh: Mesh,
         return x[None], jax.tree.map(lambda a: a[None], vs)
 
     # replication checking off: vmap-over-psum inside shard_map needs the
-    # legacy collective batching rule (jax 0.8 limitation).
-    mapped = _shard_map(shard_body, mesh=mesh, in_specs=(P(), specs),
-                        out_specs=(P(GRAPH_AXIS), P(GRAPH_AXIS)),
-                        **_SHARD_MAP_KW)
+    # unchecked collective batching rule
+    mapped = jax.shard_map(shard_body, mesh=mesh, in_specs=(P(), specs),
+                           out_specs=(P(GRAPH_AXIS), P(GRAPH_AXIS)),
+                           check_vma=False)
     return jax.jit(mapped)
 
 
@@ -273,9 +263,10 @@ def build_dist_train_step(cfg: FastEGNNConfig, mesh: Mesh, opt: Adam,
         return loss[None]
 
     def loss_fn(params, sb):
-        per_shard = _shard_map(shard_loss, mesh=mesh, in_specs=(P(), specs),
-                               out_specs=P(GRAPH_AXIS),
-                               **_SHARD_MAP_KW)(params, sb)
+        per_shard = jax.shard_map(shard_loss, mesh=mesh,
+                                  in_specs=(P(), specs),
+                                  out_specs=P(GRAPH_AXIS),
+                                  check_vma=False)(params, sb)
         return jnp.mean(per_shard)  # identical on every shard already
 
     @jax.jit

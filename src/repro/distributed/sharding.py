@@ -165,19 +165,16 @@ def process_shard_range(n_shards: int, process_index: Optional[int] = None,
 def sharded_batch_from_process_local(mesh: Mesh, host: dict):
     """Process-local ``(D_local, B, ...)`` numpy fields → global ShardedBatch.
 
-    Single-process this is exactly ``sharded_batch_to_device`` (one host
-    owns every shard).  Multi-process, each field becomes a global
-    ``(D, B, ...)`` array via ``jax.make_array_from_process_local_data``
-    under ``P('graph')`` sharding: the local rows land on this process's
-    devices, the global shape is inferred from the identical per-process
-    local shape, and no cross-host copy of shard *data* ever happens —
-    host memory and build time stay flat in the host count.
+    Each field becomes a global ``(D, B, ...)`` array via
+    ``jax.make_array_from_process_local_data`` under ``P('graph')``
+    sharding: the local rows land on this process's devices, the global
+    shape is inferred from the identical per-process local shape, and no
+    cross-host copy of shard *data* ever happens — host memory and build
+    time stay flat in the host count.  Single-process this is exactly
+    ``sharded_batch_to_device(host, mesh)`` (one host owns every shard).
     """
-    from repro.distributed.dist_egnn import (GRAPH_AXIS, ShardedBatch,
-                                             sharded_batch_to_device)
+    from repro.distributed.dist_egnn import GRAPH_AXIS, ShardedBatch
 
-    if jax.process_count() == 1:
-        return sharded_batch_to_device(host)
     sharding = NamedSharding(mesh, P(GRAPH_AXIS))
     return ShardedBatch(**{
         f: jax.make_array_from_process_local_data(

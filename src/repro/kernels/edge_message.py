@@ -85,8 +85,8 @@ Precision contract
 Both directions take a static ``precision`` (``kernels.runtime.Precision``):
 operands are cast to ``precision.compute`` before every MXU matmul while
 ``preferred_element_type=precision.accumulate`` keeps segment sums and
-weight-gradient accumulation wide.  The f32 default is bit-compatible with
-the pre-contract kernel; bf16 compute halves the streamed x/h bytes.
+weight-gradient accumulation wide.  The f32 default contracts at full f32
+precision (``_mm``); bf16 compute halves the streamed x/h bytes.
 """
 from __future__ import annotations
 
@@ -159,7 +159,20 @@ def layout_from_host(bcsr) -> EdgeLayout:
 
 LANE = 128  # TPU lane width: one-hot minor dims should be multiples of this
 DEFAULT_WINDOW = 512  # receiver-window rows (scatter band)
-DEFAULT_SWINDOW = 4096  # sender-window rows (gather band)
+DEFAULT_SWINDOW = 2048  # sender-window rows (gather band)
+#: scoped-VMEM limit every edge pallas_call is compiled with.  It is also
+#: the limit XLA holds a kernel to once vmap has wrapped it in a per-sample
+#: loop (batched scalar-prefetch operands), so raising it here would not
+#: raise it there.  ``message_passing.kernel_supported`` budgets against
+#: this same number, so eligibility and the compiler agree (DESIGN.md §3.2).
+VMEM_LIMIT_BYTES = 16 * 2**20
+
+
+def _compiler_params():
+    # the grid is a sequential walk: output blocks are revisited across
+    # consecutive steps (window runs, whole-grid weight-grad accumulators)
+    return pltpu.CompilerParams(dimension_semantics=("arbitrary",),
+                                vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 
 def _round_up(v: int, m: int) -> int:
@@ -252,8 +265,15 @@ def banded_layout(snd: Array, rcv: Array, em: Array, *, n_pad: int,
 
 
 def _mm(a: Array, b: Array, *, cdt, adt) -> Array:
-    """The precision-contract matmul: compute-dtype operands, wide result."""
-    return jnp.matmul(a.astype(cdt), b.astype(cdt), preferred_element_type=adt)
+    """The precision-contract matmul: compute-dtype operands, wide result.
+
+    f32 compute asks Mosaic for full f32 contraction.  At its default
+    precision an f32 dot runs as a bf16 pass: on a v5e a one-hot gather of
+    unit-range values was then off by 2e-3, about 3 significant digits.
+    """
+    prec = jax.lax.Precision.HIGHEST if cdt == jnp.float32 else None
+    return jnp.matmul(a.astype(cdt), b.astype(cdt), preferred_element_type=adt,
+                      precision=prec)
 
 
 def _silu_grad(u: Array) -> Array:
@@ -479,6 +499,7 @@ def edge_pathway_fused(
             jax.ShapeDtypeStruct((n_pad, 1), out_dt),
         ),
         interpret=interpret,
+        compiler_params=_compiler_params(),
     )(block_rwin, block_swin, snd2, rcv2, em2, x, h, x, h, *ws)
     return dx[:n], mh[:n], deg[:n]
 
@@ -720,8 +741,12 @@ def edge_pathway_bwd_fused(
     eblk = pl.BlockSpec((block_e, 1), lambda b, rw, sw: (b, 0))
     rblk = lambda width: pl.BlockSpec((window, width),
                                       lambda b, rw, sw: (rw[b], 0))
+    # sender-window blocks are single-buffered in both backward passes:
+    # their double buffers are the largest term of the VMEM budget, and
+    # the window changes only at band boundaries
     sblk = lambda width: pl.BlockSpec((swindow, width),
-                                      lambda b, rw, sw: (sw[b], 0))
+                                      lambda b, rw, sw: (sw[b], 0),
+                                      pipeline_mode=pl.Buffered(1))
     grid_a = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(n_blocks,),
@@ -743,6 +768,7 @@ def edge_pathway_bwd_fused(
         out_shape=(f((n_pad, 3)), f((n_pad, dh)))
         + tuple(f(a.shape) for a in weights),
         interpret=interpret,
+        compiler_params=_compiler_params(),
     )(block_rwin, block_swin, snd2, rcv2, em2,
       g_dx, g_mh, inv, x, h, x, h, *ws)
 
@@ -756,7 +782,8 @@ def edge_pathway_bwd_fused(
     rblk_p = lambda width: pl.BlockSpec((window, width),
                                         lambda j, pm, rp, sp: (rp[j], 0))
     sblk_p = lambda width: pl.BlockSpec((swindow, width),
-                                        lambda j, pm, rp, sp: (sp[j], 0))
+                                        lambda j, pm, rp, sp: (sp[j], 0),
+                                        pipeline_mode=pl.Buffered(1))
     grid_b = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(n_blocks,),
@@ -775,6 +802,7 @@ def edge_pathway_bwd_fused(
         grid_spec=grid_b,
         out_shape=(f((n_pad, 3)), f((n_pad, dh))),
         interpret=interpret,
+        compiler_params=_compiler_params(),
     )(perm, rw_p, sw_p, snd2, rcv2, em2,
       g_dx, g_mh, inv, x, h, x, h, *ws)
     # sender windows no block gathers from are never visited → mask, don't

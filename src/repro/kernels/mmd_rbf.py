@@ -22,6 +22,11 @@ from jax.experimental import pallas as pl
 
 Array = jax.Array
 
+# full-f32 contraction: the expanded ‖x‖² − 2x·z + ‖z‖² cancels, so a
+# reduced-precision dot would dominate the distance error
+_dot = functools.partial(jnp.matmul, precision=jax.lax.Precision.HIGHEST,
+                         preferred_element_type=jnp.float32)
+
 
 def _kernel(x_ref, mask_ref, z_ref, out_ref, *, inv_two_sigma2: float):
     i = pl.program_id(0)
@@ -35,11 +40,12 @@ def _kernel(x_ref, mask_ref, z_ref, out_ref, *, inv_two_sigma2: float):
     z = z_ref[...]  # (C, 3)
     d2 = (
         jnp.sum(xb * xb, axis=-1, keepdims=True)
-        - 2.0 * xb @ z.T
+        - 2.0 * _dot(xb, z.T)
         + jnp.sum(z * z, axis=-1)[None, :]
     )  # (BN, C)
     k = jnp.exp(-d2 * inv_two_sigma2)
-    out_ref[0, 0] += jnp.sum(k * mb)
+    # (1, 1) vector store: Mosaic refuses scalar stores to VMEM
+    out_ref[...] += jnp.sum(k * mb, axis=(0, 1), keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("sigma", "block_n", "interpret"))
@@ -84,18 +90,19 @@ def _grad_kernel(x_ref, mask_ref, z_ref, g_ref, dx_ref, dz_ref,
     xb = x_ref[...]  # (BN, 3)
     mb = mask_ref[...]  # (BN, 1)
     z = z_ref[...]  # (C, 3)
-    g = g_ref[0, 0]  # scalar output cotangent
+    g = g_ref[...]  # (1, 1) output cotangent, broadcast over the block
     d2 = (
         jnp.sum(xb * xb, axis=-1, keepdims=True)
-        - 2.0 * xb @ z.T
+        - 2.0 * _dot(xb, z.T)
         + jnp.sum(z * z, axis=-1)[None, :]
     )  # (BN, C)
     w = jnp.exp(-d2 * inv_two_sigma2) * mb * g  # weighted kernel matrix
     inv_s2 = 2.0 * inv_two_sigma2  # 1/σ²
     # d k(x_i,z_c) / d x_i = −k·(x_i − z_c)/σ²; contract over channels/nodes
     # without ever materialising (N, C) outside VMEM
-    dx_ref[...] = -inv_s2 * (xb * jnp.sum(w, axis=-1, keepdims=True) - w @ z)
-    dz_ref[...] += inv_s2 * (w.T @ xb - jnp.sum(w, axis=0)[:, None] * z)
+    dx_ref[...] = -inv_s2 * (xb * jnp.sum(w, axis=-1, keepdims=True)
+                             - _dot(w, z))
+    dz_ref[...] += inv_s2 * (_dot(w.T, xb) - jnp.sum(w, axis=0)[:, None] * z)
 
 
 @functools.partial(jax.jit, static_argnames=("sigma", "block_n", "interpret"))

@@ -28,6 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.dtypes import float0
 
+from repro.core.mlp import dot_precision
 from repro.kernels.edge_message import (edge_pathway_bwd_fused,
                                         edge_pathway_fused)
 from repro.kernels.mmd_rbf import mmd_cross_grads, mmd_cross_sum
@@ -211,8 +212,9 @@ def unpack_virtual_block(vb, s: Array, mv: Array, h_dim: int):
     w1d = w1[:, h_dim + s_dim, :]
     w1mv = w1[:, h_dim + s_dim + 1 :, :]  # (C, C, hid)
     const1 = (
-        jnp.einsum("cs,csh->ch", s, w1s)
-        + jnp.einsum("ck,ckh->ch", mv.T, w1mv)
+        jnp.einsum("cs,csh->ch", s, w1s, precision=dot_precision(s, w1s))
+        + jnp.einsum("ck,ckh->ch", mv.T, w1mv,
+                     precision=dot_precision(mv, w1mv))
         + b1
     )
     return dict(

@@ -8,10 +8,8 @@ silently ran the *emulated* kernels on a real TPU for anyone calling them
 directly.  This is now the single home of that decision:
 
 * :func:`default_interpret` — should Pallas kernels run in interpret mode
-  on this backend?  (Everything that is not a TPU interprets.  The
-  ``REPRO_INTERPRET`` env var forces the answer either way — CI's tier-1
-  matrix sets ``REPRO_INTERPRET=1`` so the kernel suites exercise the
-  emulated kernels deterministically regardless of backend.)
+  on this backend?  Everything that is not a TPU interprets; nothing else
+  steers the answer (tests that need to monkeypatch this function).
 * :func:`resolve_interpret` — resolve a kernel's ``interpret`` argument:
   ``None`` (the kernels' new default) auto-detects, an explicit bool is
   honoured (tests force ``interpret=True`` to exercise emulation on any
@@ -38,7 +36,6 @@ into the kernels, and pairs with ``TrainConfig.loss_scale`` in the trainer.
 """
 from __future__ import annotations
 
-import os
 from typing import NamedTuple, Union
 
 import jax
@@ -46,13 +43,7 @@ import jax.numpy as jnp
 
 
 def default_interpret() -> bool:
-    """True unless running on a real TPU backend (Pallas compiles there).
-
-    ``REPRO_INTERPRET=1`` / ``0`` in the environment overrides the
-    auto-detection (CI forces interpret mode explicitly)."""
-    env = os.environ.get("REPRO_INTERPRET")
-    if env is not None and env != "":
-        return env not in ("0", "false", "False")
+    """True unless running on a real TPU backend (Pallas compiles there)."""
     return jax.default_backend() != "tpu"
 
 

@@ -24,19 +24,15 @@ def init_distributed(coordinator_address: str, num_processes: int,
     backend cross-process collectives need an explicit implementation —
     without ``jax_cpu_collectives_implementation`` the first psum raises
     "Multiprocess computations aren't implemented on the CPU backend" —
-    so ``cpu_collectives`` (default ``'gloo'``) is applied first when the
-    running jax exposes the flag (TPU/GPU runs ignore it; pass ``None``
-    to skip).  After this returns, ``jax.devices()`` spans every process
-    and ``dist_egnn.make_gnn_mesh`` builds the global graph mesh; each
-    host then feeds only its own shards through the process-sharded
-    stream (DESIGN.md §11).
+    so ``cpu_collectives`` (default ``'gloo'``) is applied first (TPU/GPU
+    runs ignore it; pass ``None`` to skip).  After this returns,
+    ``jax.devices()`` spans every process and ``dist_egnn.make_gnn_mesh``
+    builds the global graph mesh; each host then feeds only its own shards
+    through the process-sharded stream (DESIGN.md §11).
     """
     if cpu_collectives is not None:
-        try:
-            jax.config.update("jax_cpu_collectives_implementation",
-                              cpu_collectives)
-        except Exception:
-            pass  # older/newer jax without the flag: backend default
+        jax.config.update("jax_cpu_collectives_implementation",
+                          cpu_collectives)
     jax.distributed.initialize(coordinator_address=coordinator_address,
                                num_processes=int(num_processes),
                                process_id=int(process_id))

@@ -75,9 +75,11 @@ def main(argv=None) -> int:
 
     import jax
 
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.pipeline import build_pipeline
     from repro.serving import RolloutService
 
+    enable_compile_cache()
     x0, v0, h = load_scene(args)
     n = x0.shape[0]
     r = args.r if args.r is not None else float(

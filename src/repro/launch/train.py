@@ -7,9 +7,11 @@ Two modes:
     Both device counts go through the one pipeline API (DESIGN.md §7):
     ``build_pipeline(name, key, mesh=...)`` + ``pipe.make_batches`` +
     ``pipe.fit`` — ``--devices 1`` drives the vmap trainer over
-    layout-carrying GraphBatches, ``--devices > 1`` re-executes itself
-    with forced host devices and drives the shard_map DistEGNN path
-    (model pinned to fast_egnn, the paper's Sec. VI architecture).
+    layout-carrying GraphBatches, ``--devices > 1`` drives the shard_map
+    DistEGNN path over that many of the devices JAX finds (model pinned to
+    fast_egnn, the paper's Sec. VI architecture) and fails when there are
+    fewer.  A CPU rehearsal sets
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=D`` itself.
   * LM mode (assigned pool): short real-data-free training run of a reduced
     architecture —
       python -m repro.launch.train lm --arch gemma3-12b --steps 100
@@ -17,8 +19,6 @@ Two modes:
 from __future__ import annotations
 
 import argparse
-import os
-import sys
 import time
 
 
@@ -30,12 +30,16 @@ def gnn_main(args):
     from repro.training.checkpoint import save_checkpoint
     from repro.training.trainer import TrainConfig
 
-    if args.devices > 1:
-        # DistEGNN needs D host devices before jax initialises: re-exec once
-        want = f"--xla_force_host_platform_device_count={args.devices}"
-        if os.environ.get("XLA_FLAGS", "") != want:
-            os.environ["XLA_FLAGS"] = want
-            os.execv(sys.executable, [sys.executable] + sys.argv)
+    from repro.launch.compile_cache import enable_compile_cache
+
+    have = len(jax.devices())
+    if have < args.devices:
+        # CPU rehearsals get their devices from the caller's XLA_FLAGS
+        # (--xla_force_host_platform_device_count); nothing is emulated here
+        raise SystemExit(f"--devices {args.devices} needs {args.devices} "
+                         f"devices, but JAX found {have}: "
+                         f"{jax.devices()}")
+    enable_compile_cache()
 
     if args.dataset == "nbody":
         from repro.data.nbody import generate_nbody_dataset

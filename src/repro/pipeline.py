@@ -361,4 +361,10 @@ def build_pipeline(name: str, key, *, mesh=None,
             f"is FastEGNN under graph-partition shard_map — got model "
             f"{name!r}; pass name='fast_egnn' or mesh=None")
     cfg, params, apply_full = resolve_model(name, key, **cfg_overrides)
+    if mesh is not None:
+        # replicated up front: every chip holds its own copy, none is
+        # staged through device 0 on the first step
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        params = jax.device_put(params, NamedSharding(mesh, PartitionSpec()))
     return Pipeline(name, cfg, params, apply_full, mesh, train_cfg)

@@ -1261,7 +1261,7 @@ class DistRolloutEngine:
         self._tel.uploaded(*(host[k] for k in edge_keys), edges=True)
         self._tel.uploaded(*(v for k, v in host.items()
                              if k not in edge_keys))
-        return sharded_batch_to_device(host)
+        return sharded_batch_to_device(host, self.mesh)
 
     def _build_rebuild(self) -> Callable:
         """Per-shard device rebuild under ``shard_map``: each shard runs
@@ -1272,8 +1272,7 @@ class DistRolloutEngine:
         (``pick_windows`` defaults, ``EDGE_KERNEL_BLOCK_E``, capacity from
         the padded edge count) — bitwise the same ``lay_*`` fields."""
         from repro.core.message_passing import EDGE_KERNEL_BLOCK_E
-        from repro.distributed.dist_egnn import (GRAPH_AXIS, _shard_map,
-                                                 _SHARD_MAP_KW)
+        from repro.distributed.dist_egnn import GRAPH_AXIS
         from jax.sharding import PartitionSpec as P
 
         r_build = self.r + self.skin
@@ -1295,10 +1294,10 @@ class DistRolloutEngine:
                     lay.receivers[None], lay.edge_mask[None],
                     lay.block_rwin[None], lay.block_swin[None], flags)
 
-        mapped = _shard_map(
+        mapped = jax.shard_map(
             shard_rebuild, mesh=self.mesh,
             in_specs=(P(GRAPH_AXIS), P(GRAPH_AXIS)),
-            out_specs=(P(GRAPH_AXIS),) * 8 + (P(),), **_SHARD_MAP_KW)
+            out_specs=(P(GRAPH_AXIS),) * 8 + (P(),), check_vma=False)
 
         def rebuild(x, nm):
             self._tel.rebuild_traces += 1
@@ -1356,8 +1355,7 @@ class DistRolloutEngine:
         one trace, exactly like :meth:`RolloutEngine._build_chunk`.
         """
         from repro.distributed.dist_egnn import (GRAPH_AXIS, ShardedBatch,
-                                                 _edge_layout, _local_graph,
-                                                 _shard_map, _SHARD_MAP_KW)
+                                                 _edge_layout, _local_graph)
         from jax.sharding import PartitionSpec as P
 
         r2 = np.float32(self.r) ** 2
@@ -1412,10 +1410,10 @@ class DistRolloutEngine:
 
         sb_specs = ShardedBatch(
             *([P(GRAPH_AXIS)] * len(ShardedBatch._fields)))
-        mapped = _shard_map(
+        mapped = jax.shard_map(
             shard_body, mesh=self.mesh,
             in_specs=(P(), sb_specs) + (P(GRAPH_AXIS),) * 5 + (P(),) * 4,
-            out_specs=(P(GRAPH_AXIS),) * 4, **_SHARD_MAP_KW)
+            out_specs=(P(GRAPH_AXIS),) * 4, check_vma=False)
 
         def chunk(params, sb, x, v, ref_a, ref_b, traj,
                   start, budget, lim_a2, lim_b2):
@@ -1429,7 +1427,10 @@ class DistRolloutEngine:
     def run(self, params, x0, v0, h, n_steps: int, *,
             targets: Optional[np.ndarray] = None,
             traj_capacity: Optional[int] = None) -> RolloutResult:
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
         from repro.data.stream import shared_worker_pool
+        from repro.distributed.dist_egnn import GRAPH_AXIS
 
         n_steps = int(n_steps)
         if n_steps <= 0:
@@ -1466,8 +1467,10 @@ class DistRolloutEngine:
         # monotone buffer capacity, same contract as RolloutEngine.run:
         # shorter re-runs reuse the compiled chunk with zero retraces
         self._traj_cap = max(self._traj_cap, n_steps, int(traj_capacity or 0))
+        # placed as the chunk returns it, so later calls hit the first trace
         traj = jnp.zeros((self.d, self._traj_cap, self._n_cap, 3),
-                         jnp.float32)
+                         jnp.float32, device=NamedSharding(self.mesh,
+                                                           P(GRAPH_AXIS)))
 
         inf = np.float32(np.inf)
         lim2 = np.float32((0.5 * self.skin) ** 2)

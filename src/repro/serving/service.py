@@ -99,6 +99,9 @@ class StreamingResponse:
         self.queue_wait_s: Optional[float] = None
         self.first_frame_s: Optional[float] = None
         self.latency_s: Optional[float] = None
+        # the batched rollout this scene rode in, set by the service
+        self.rebuilds: Optional[int] = None
+        self.recompiles: Optional[int] = None
 
     # ---- service side
     def _push(self, block: np.ndarray) -> None:
@@ -355,6 +358,8 @@ class RolloutService:
             h.queue_wait_s = t_dispatch - p.enqueue_t
             h.first_frame_s = ((p.first_frame_t or t_done) - p.enqueue_t)
             h.latency_s = t_done - p.enqueue_t
+            h.rebuilds = res.rebuild_count
+            h.recompiles = res.recompiles
             self._metrics.record_request(
                 queue_wait_s=h.queue_wait_s, first_frame_s=h.first_frame_s,
                 latency_s=h.latency_s, done_t=t_done)

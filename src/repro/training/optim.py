@@ -10,6 +10,7 @@ from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec
 
 Array = jax.Array
 
@@ -18,6 +19,19 @@ class AdamState(NamedTuple):
     step: Array
     m: any
     v: any
+
+
+def _step_zero(params) -> Array:
+    """Step count 0, replicated over the params' mesh when they have one:
+    a jitted update returns it there, and a step count placed elsewhere on
+    the first call would retrace the update on the second."""
+    sharding = getattr(next(iter(jax.tree.leaves(params)), None), "sharding",
+                       None)
+    step = jnp.zeros((), jnp.int32)
+    if isinstance(sharding, NamedSharding):
+        step = jax.device_put(step, NamedSharding(sharding.mesh,
+                                                  PartitionSpec()))
+    return step
 
 
 class Adam(NamedTuple):
@@ -30,7 +44,7 @@ class Adam(NamedTuple):
 
     def init(self, params) -> AdamState:
         zeros = jax.tree.map(lambda p: jnp.zeros_like(p), params)
-        return AdamState(step=jnp.zeros((), jnp.int32), m=zeros,
+        return AdamState(step=_step_zero(params), m=zeros,
                          v=jax.tree.map(lambda p: jnp.zeros_like(p), params))
 
     def update(self, grads, state: AdamState, params):

@@ -93,8 +93,8 @@ def test_pick_windows_policy():
     for n in [1, 33, 128, 600, 4096, 4097, 8192, 65536, 113000]:
         w, sw, n_pad = pick_windows(n)
         assert sw % w == 0 and n_pad % sw == 0 and n_pad >= n
-    assert pick_windows(8192) == (512, 4096, 8192)
-    assert pick_windows(65536) == (512, 4096, 65536)
+    assert pick_windows(8192) == (512, 2048, 8192)
+    assert pick_windows(65536) == (512, 2048, 65536)
     assert pick_windows(100)[:2] == (128, 128)
 
 

@@ -252,6 +252,24 @@ def test_kernel_equivariance_rotation_translation(precision):
                                np.asarray(x2) / scale, **tol)
 
 
+@pytest.mark.parametrize("use_kernel", [False, True])
+def test_f32_dots_pin_highest_precision(use_kernel):
+    """The f32 configuration contracts in f32 on every backend: each dot of
+    the FastEGNN forward and backward, the model's own and (interpreted)
+    the kernels', carries ``precision=HIGHEST`` — a TPU runs a default
+    precision f32 dot as one bf16 pass."""
+    g = _graph(seed=17)
+    cfg, params, apply_full = resolve_model(
+        "fast_egnn", jax.random.PRNGKey(18), use_kernel=use_kernel, **_CFG,
+        n_virtual=2, s_dim=8)
+    text = jax.jit(lambda p: _grad_tree(apply_full, cfg, p, g)).lower(
+        params).as_text()
+    dots = [ln for ln in text.splitlines() if "dot_general" in ln]
+    assert dots
+    assert all("precision = [HIGHEST, HIGHEST]" in ln for ln in dots), [
+        ln for ln in dots if "HIGHEST" not in ln][:3]
+
+
 # ------------------------------------------------- train-step acceptance
 def test_train_step_dispatch_acceptance():
     """The PR's acceptance telemetry: a single-device FastEGNN training
