@@ -49,27 +49,28 @@ def mmd_loss(
     Same ``use_kernel``-style switch as the edge pathway: identical math,
     parity-tested fwd + grad in ``tests/test_kernels.py``.  The gather for
     the sampled cross term happens *outside* the kernel, so sampling and
-    the kernel compose.
+    the kernel compose.  Runs under the name scope ``mmd_loss``.
     """
-    c = z.shape[0]
-    k_zz = rbf_kernel(z, z, sigma)
-    term_vv = jnp.sum(k_zz) / (c * c)
+    with jax.named_scope("mmd_loss"):
+        c = z.shape[0]
+        k_zz = rbf_kernel(z, z, sigma)
+        term_vv = jnp.sum(k_zz) / (c * c)
 
-    if sample_size is not None and key is not None:
-        logits = jnp.where(node_mask > 0, 0.0, -1e9)
-        idx = jax.random.categorical(key, logits, shape=(sample_size,))
-        xs = x[idx]
-        w = jnp.ones((sample_size,), x.dtype)
-    else:
-        xs = x
-        w = node_mask
-    denom = jnp.maximum(jnp.sum(w), 1.0) * c
-    if use_kernel:
-        from repro.core.message_passing import record_dispatch
-        from repro.kernels.ops import mmd_cross
+        if sample_size is not None and key is not None:
+            logits = jnp.where(node_mask > 0, 0.0, -1e9)
+            idx = jax.random.categorical(key, logits, shape=(sample_size,))
+            xs = x[idx]
+            w = jnp.ones((sample_size,), x.dtype)
+        else:
+            xs = x
+            w = node_mask
+        denom = jnp.maximum(jnp.sum(w), 1.0) * c
+        if use_kernel:
+            from repro.core.message_passing import record_dispatch
+            from repro.kernels.ops import mmd_cross
 
-        record_dispatch("mmd_kernel")
-        return term_vv - mmd_cross(xs, z, w, sigma) / denom
-    k_xz = rbf_kernel(xs, z, sigma)  # (M, C)
-    term_xv = jnp.sum(k_xz * w[:, None]) / denom
-    return term_vv - term_xv
+            record_dispatch("mmd_kernel")
+            return term_vv - mmd_cross(xs, z, w, sigma) / denom
+        k_xz = rbf_kernel(xs, z, sigma)  # (M, C)
+        term_xv = jnp.sum(k_xz * w[:, None]) / denom
+        return term_vv - term_xv

@@ -80,6 +80,12 @@ contribute exact zeros) and is **not differentiated** — ``ops.edge_pathway``
 returns a zero cotangent for it, along with float0 for the integer
 endpoints and zeros for a threaded layout.
 
+Each pass's ``pallas_call`` carries its own ``name``
+(``edge_pathway_fused_fwd``, ``edge_pathway_bwd_fused_recv``,
+``edge_pathway_bwd_fused_send``), which the compiled program keeps in its
+``op_name`` metadata, also where ``vmap`` wraps the call in a per-sample
+loop: a profile tells the three passes apart by it.
+
 Precision contract
 ------------------
 Both directions take a static ``precision`` (``kernels.runtime.Precision``):
@@ -493,6 +499,7 @@ def edge_pathway_fused(
     dx, mh, deg = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
+        name="edge_pathway_fused_fwd",
         out_shape=(
             jax.ShapeDtypeStruct((n_pad, 3), out_dt),
             jax.ShapeDtypeStruct((n_pad, m), out_dt),
@@ -765,6 +772,7 @@ def edge_pathway_bwd_fused(
     dxr, dhr, *gws = pl.pallas_call(
         functools.partial(_edge_bwd_r_kernel, **kw),
         grid_spec=grid_a,
+        name="edge_pathway_bwd_fused_recv",
         out_shape=(f((n_pad, 3)), f((n_pad, dh)))
         + tuple(f(a.shape) for a in weights),
         interpret=interpret,
@@ -800,6 +808,7 @@ def edge_pathway_bwd_fused(
     dxs, dhs = pl.pallas_call(
         functools.partial(_edge_bwd_s_kernel, **kw),
         grid_spec=grid_b,
+        name="edge_pathway_bwd_fused_send",
         out_shape=(f((n_pad, 3)), f((n_pad, dh))),
         interpret=interpret,
         compiler_params=_compiler_params(),

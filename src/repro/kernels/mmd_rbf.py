@@ -75,6 +75,7 @@ def mmd_cross_sum(x: Array, z: Array, node_mask: Array, *, sigma: float,
         out_specs=pl.BlockSpec((1, 1), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, 1), x.dtype),
         interpret=interpret,
+        name="mmd_cross_sum_fwd",
     )(x, node_mask[:, None], z)
     return out[0, 0]
 
@@ -142,5 +143,6 @@ def mmd_cross_grads(x: Array, z: Array, node_mask: Array, g: Array, *,
             jax.ShapeDtypeStruct((c, 3), x.dtype),
         ),
         interpret=interpret,
+        name="mmd_cross_grads_bwd",
     )(x, node_mask[:, None], z, jnp.asarray(g, x.dtype).reshape(1, 1))
     return dx[:n], dz

@@ -147,6 +147,7 @@ def virtual_pathway_fused(
         ),
         out_shape=out_shapes,
         interpret=interpret,
+        name="virtual_pathway_fused_fwd",
     )(x, h, mask2d, *ws)
     return dx[:n], mh[:n], dz, ms
 
@@ -308,6 +309,7 @@ def virtual_pathway_bwd_fused(
             f((c, hid, hid)), f((c, hid)), f((c, hid, 1)),
         ),
         interpret=interpret,
+        name="virtual_pathway_bwd_fused_grads",
     )(x, h, mask2d, ws[0], g_dx, g_mh, g_dz, g_ms, *ws[1:])
     gx, gh, *rest = out
     return (gx[:n], gh[:n], *rest)

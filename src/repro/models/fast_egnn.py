@@ -111,6 +111,11 @@ def fast_egnn_apply(
     banded layout for the real-real pathway: with ``cfg.use_kernel`` the
     fused kernel consumes it directly instead of regrouping at trace time
     (DESIGN.md §6.6); ignored on the jnp path.
+
+    Layer ``k`` runs under the name scope ``layer_<k>``, and inside it
+    ``virtual_pathway`` (CoM, Eqs. 4-5, 8-9, and DistEGNN's psums),
+    ``edge_pathway`` and ``node_update``: the compiled program's op names,
+    and so a profile, attribute each operation to its layer and part.
     """
     h = mlp(params["embed"], g.h)
     x = g.x
@@ -123,22 +128,48 @@ def fast_egnn_apply(
 
     from repro.core.message_passing import record_dispatch
 
-    for lp in params["layers"]:
+    for k, lp in enumerate(params["layers"]):
         if axis_name is not None:
             # two serialized collective groups per layer: the CoM psum and
             # the Eqs. 16–17 aggregate psum both complete before any
             # dependent compute is issued (cf. 'collective_overlapped')
             record_dispatch("collective_serialized")
             record_dispatch("collective_serialized")
-        com = masked_com(x, g.node_mask, axis_name)  # Alg. 1 line 4
-        mv = virtual_global_message(vs.z, com)  # Eq. 4
-        dx_v, mh_v, dz_sum, ms_sum = virtual_pathway(
-            lp["virtual"], h, x, vs, mv, g.node_mask,
-            use_kernel=cfg.use_kernel, precision=cfg.precision)  # Eq. 5
-        dx_r, mh_r = real_real_pathway(lp, h, x, g, cfg.coord_clamp,
-                                       cfg.use_kernel,
-                                       edge_layout=edge_layout,
-                                       precision=cfg.precision)  # Eqs. 3, 6-7
+        with jax.named_scope(f"layer_{k}"):
+            with jax.named_scope("virtual_pathway"):
+                com = masked_com(x, g.node_mask, axis_name)  # Alg. 1 line 4
+                mv = virtual_global_message(vs.z, com)  # Eq. 4
+                dx_v, mh_v, dz_sum, ms_sum = virtual_pathway(
+                    lp["virtual"], h, x, vs, mv, g.node_mask,
+                    use_kernel=cfg.use_kernel,
+                    precision=cfg.precision)  # Eq. 5
+            dx_r, mh_r = _edge_pathway(lp, cfg, h, x, g, edge_layout)
+            x_new, h = _node_update(lp, cfg, g, h, x, dx_r, mh_r, dx_v, mh_v)
+            # Eqs. 8–9 / 16–17 use the pre-update coordinates x^{(l)}.
+            with jax.named_scope("virtual_pathway"):
+                vs = virtual_aggregate_from_sums(
+                    lp["virtual"], vs, dz_sum, ms_sum, jnp.sum(g.node_mask),
+                    axis_name)
+        x = x_new
+    return x, h, vs
+
+
+def _edge_pathway(lp, cfg: FastEGNNConfig, h: Array, x: Array,
+                  g: GeometricGraph, edge_layout) -> tuple[Array, Array]:
+    """The real-real pathway (Eqs. 3, 6-7), under the scope
+    ``edge_pathway``."""
+    with jax.named_scope("edge_pathway"):
+        return real_real_pathway(lp, h, x, g, cfg.coord_clamp,
+                                 cfg.use_kernel, edge_layout=edge_layout,
+                                 precision=cfg.precision)
+
+
+def _node_update(lp, cfg: FastEGNNConfig, g: GeometricGraph, h: Array,
+                 x: Array, dx_r: Array, mh_r: Array, dx_v: Array,
+                 mh_v: Array) -> tuple[Array, Array]:
+    """Eqs. 6-7 from both pathways' terms, under the scope
+    ``node_update``; returns ``(x^{(l+1)}, h^{(l+1)})``."""
+    with jax.named_scope("node_update"):
         # clamp the virtual term like the real-real term (official EGNN
         # practice): an unbounded gate feeds the |x|→|d²| runaway loop.
         # Norm rescale, not componentwise clip — the clip box is
@@ -148,12 +179,9 @@ def fast_egnn_apply(
         if cfg.velocity:
             dx = dx + mlp(lp["phi_v"], h) * g.v
         x_new = x + dx * g.node_mask[:, None]  # Eq. 6
-        h = h + mlp(lp["phi_h"], jnp.concatenate([h, mh_r, mh_v], axis=-1))  # Eq. 7
-        # Eqs. 8–9 / 16–17 use the pre-update coordinates x^{(l)}.
-        vs = virtual_aggregate_from_sums(lp["virtual"], vs, dz_sum, ms_sum,
-                                         jnp.sum(g.node_mask), axis_name)
-        x = x_new
-    return x, h, vs
+        h_new = h + mlp(lp["phi_h"],
+                        jnp.concatenate([h, mh_r, mh_v], axis=-1))  # Eq. 7
+    return x_new, h_new
 
 
 def _apply_overlapped(params, cfg: FastEGNNConfig, g: GeometricGraph,
@@ -171,39 +199,40 @@ def _apply_overlapped(params, cfg: FastEGNNConfig, g: GeometricGraph,
     window XLA's latency-hiding scheduler overlaps.  The psum operands,
     reduction order and epilogue math are unchanged, so the result is
     float-identical to the serialized schedule (the parity test in
-    ``tests/test_multiprocess.py`` pins this).
+    ``tests/test_multiprocess.py`` pins this).  Name scopes as in
+    :func:`fast_egnn_apply`; layer ``l``'s aggregate epilogue is named
+    where it runs, under layer ``l+1``'s ``virtual_pathway``.
     """
     from repro.core.message_passing import record_dispatch
 
     pending = None  # (layer_params, vs, dz, ms, n): psums in flight
-    for lp in params["layers"]:
-        record_dispatch("collective_overlapped")  # CoM psum, issued early
-        tot, cnt = masked_com_sums(x, g.node_mask, axis_name)
-        dx_r, mh_r = real_real_pathway(lp, h, x, g, cfg.coord_clamp,
-                                       cfg.use_kernel,
-                                       edge_layout=edge_layout,
-                                       precision=cfg.precision)  # Eqs. 3, 6-7
-        if pending is not None:  # consume layer l-1's aggregate psums
-            vs = finish_virtual_aggregate(*pending)
-            pending = None
-        com = tot / jnp.maximum(cnt, 1.0)  # Alg. 1 line 4
-        mv = virtual_global_message(vs.z, com)  # Eq. 4
-        dx_v, mh_v, dz_sum, ms_sum = virtual_pathway(
-            lp["virtual"], h, x, vs, mv, g.node_mask,
-            use_kernel=cfg.use_kernel, precision=cfg.precision)  # Eq. 5
-        dx_v = clamp_vector_norm(dx_v, cfg.coord_clamp)
-        dx = dx_r + dx_v
-        if cfg.velocity:
-            dx = dx + mlp(lp["phi_v"], h) * g.v
-        x_new = x + dx * g.node_mask[:, None]  # Eq. 6
-        h = h + mlp(lp["phi_h"], jnp.concatenate([h, mh_r, mh_v], axis=-1))  # Eq. 7
-        # Eqs. 16–17 collectives launched here (pre-update coordinates
-        # x^{(l)} — same operands as the serialized path), finished after
-        # the *next* layer's edge pathway
-        record_dispatch("collective_overlapped")
-        sums = launch_virtual_sums(dz_sum, ms_sum, jnp.sum(g.node_mask),
-                                   axis_name)
-        pending = (lp["virtual"], vs, *sums)
+    for k, lp in enumerate(params["layers"]):
+        with jax.named_scope(f"layer_{k}"):
+            record_dispatch("collective_overlapped")  # CoM psum, issued early
+            with jax.named_scope("virtual_pathway"):
+                tot, cnt = masked_com_sums(x, g.node_mask, axis_name)
+            dx_r, mh_r = _edge_pathway(lp, cfg, h, x, g, edge_layout)
+            with jax.named_scope("virtual_pathway"):
+                if pending is not None:  # consume layer l-1's aggregate psums
+                    vs = finish_virtual_aggregate(*pending)
+                    pending = None
+                com = tot / jnp.maximum(cnt, 1.0)  # Alg. 1 line 4
+                mv = virtual_global_message(vs.z, com)  # Eq. 4
+                dx_v, mh_v, dz_sum, ms_sum = virtual_pathway(
+                    lp["virtual"], h, x, vs, mv, g.node_mask,
+                    use_kernel=cfg.use_kernel,
+                    precision=cfg.precision)  # Eq. 5
+            x_new, h = _node_update(lp, cfg, g, h, x, dx_r, mh_r, dx_v, mh_v)
+            # Eqs. 16–17 collectives launched here (pre-update coordinates
+            # x^{(l)} — same operands as the serialized path), finished
+            # after the *next* layer's edge pathway
+            record_dispatch("collective_overlapped")
+            with jax.named_scope("virtual_pathway"):
+                sums = launch_virtual_sums(dz_sum, ms_sum,
+                                           jnp.sum(g.node_mask), axis_name)
+            pending = (lp["virtual"], vs, *sums)
         x = x_new
-    vs = finish_virtual_aggregate(*pending)  # drain the last layer's psums
+    # drain the last layer's psums
+    with jax.named_scope(f"layer_{k}"), jax.named_scope("virtual_pathway"):
+        vs = finish_virtual_aggregate(*pending)
     return x, h, vs

@@ -16,15 +16,17 @@ def masked_mse(pred: Array, target: Array, node_mask: Array,
     """Mean over real nodes of ‖pred − target‖² (per-coordinate mean).
 
     With ``axis_name``: global mean across shards (DistEGNN's Eq. 18 summed
-    over devices — equivalent to the full-graph MSE).
+    over devices — equivalent to the full-graph MSE).  Runs under the
+    name scope ``mse_loss``.
     """
-    err = jnp.sum((pred - target) ** 2, axis=-1) * node_mask
-    tot = jnp.sum(err)
-    cnt = jnp.sum(node_mask)
-    if axis_name is not None:
-        tot = jax.lax.psum(tot, axis_name)
-        cnt = jax.lax.psum(cnt, axis_name)
-    return tot / jnp.maximum(cnt, 1.0) / 3.0
+    with jax.named_scope("mse_loss"):
+        err = jnp.sum((pred - target) ** 2, axis=-1) * node_mask
+        tot = jnp.sum(err)
+        cnt = jnp.sum(node_mask)
+        if axis_name is not None:
+            tot = jax.lax.psum(tot, axis_name)
+            cnt = jax.lax.psum(cnt, axis_name)
+        return tot / jnp.maximum(cnt, 1.0) / 3.0
 
 
 def combined_objective(

@@ -48,24 +48,28 @@ class Adam(NamedTuple):
                          v=jax.tree.map(lambda p: jnp.zeros_like(p), params))
 
     def update(self, grads, state: AdamState, params):
-        step = state.step + 1
-        if self.grad_clip is not None:
-            gnorm = optax_global_norm(grads)
-            scale = jnp.minimum(1.0, self.grad_clip / (gnorm + 1e-9))
-            grads = jax.tree.map(lambda g: g * scale, grads)
-        lr = self.lr(step) if callable(self.lr) else self.lr
-        b1, b2 = self.b1, self.b2
-        m = jax.tree.map(lambda mm, g: b1 * mm + (1 - b1) * g, state.m, grads)
-        v = jax.tree.map(lambda vv, g: b2 * vv + (1 - b2) * g * g, state.v, grads)
-        mh_c = 1.0 - b1 ** step.astype(jnp.float32)
-        vh_c = 1.0 - b2 ** step.astype(jnp.float32)
+        """One Adam step, under the name scope ``adam_update``."""
+        with jax.named_scope("adam_update"):
+            step = state.step + 1
+            if self.grad_clip is not None:
+                gnorm = optax_global_norm(grads)
+                scale = jnp.minimum(1.0, self.grad_clip / (gnorm + 1e-9))
+                grads = jax.tree.map(lambda g: g * scale, grads)
+            lr = self.lr(step) if callable(self.lr) else self.lr
+            b1, b2 = self.b1, self.b2
+            m = jax.tree.map(lambda mm, g: b1 * mm + (1 - b1) * g,
+                             state.m, grads)
+            v = jax.tree.map(lambda vv, g: b2 * vv + (1 - b2) * g * g,
+                             state.v, grads)
+            mh_c = 1.0 - b1 ** step.astype(jnp.float32)
+            vh_c = 1.0 - b2 ** step.astype(jnp.float32)
 
-        def upd(p, mm, vv):
-            u = (mm / mh_c) / (jnp.sqrt(vv / vh_c) + self.eps)
-            return p - lr * (u + self.weight_decay * p)
+            def upd(p, mm, vv):
+                u = (mm / mh_c) / (jnp.sqrt(vv / vh_c) + self.eps)
+                return p - lr * (u + self.weight_decay * p)
 
-        new_params = jax.tree.map(upd, params, m, v)
-        return new_params, AdamState(step=step, m=m, v=v)
+            new_params = jax.tree.map(upd, params, m, v)
+            return new_params, AdamState(step=step, m=m, v=v)
 
 
 def optax_global_norm(tree) -> Array:

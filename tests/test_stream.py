@@ -101,6 +101,39 @@ def test_stream_propagates_build_errors():
         list(iter(stream))
 
 
+def test_stream_spans_on_the_host_plane(tmp_path):
+    """Each piece of the stream's work is a profiler span on the host plane,
+    on the thread that does it: over two epochs of a threaded stream,
+    ``stream.prepare`` once, ``stream.collate`` once per host batch on the
+    producer, ``stream.queue_wait`` once per item taken from the queue (the
+    batches and each epoch's end) and ``stream.to_device`` once per
+    yielded batch, both on the consumer."""
+    from jax.profiler import ProfileData
+
+    stream = BatchStream(_data(6), 2, prefetch=2, num_workers=2)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        yielded = [len(list(iter(stream))) for _ in range(2)]
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    count, threads = {}, {}  # threads: the host lines a span is on
+    for plane in ProfileData.from_file(str(path)).planes:
+        if not plane.name.startswith("/host"):
+            continue
+        for i, line in enumerate(plane.lines):
+            for e in line.events:
+                if e.name.startswith("stream."):
+                    count[e.name] = count.get(e.name, 0) + 1
+                    threads.setdefault(e.name, set()).add((plane.name, i))
+    assert yielded == [3, 3]
+    assert count == {"stream.prepare": 1, "stream.collate": 6,
+                     "stream.queue_wait": 8, "stream.to_device": 6}
+    assert len(threads["stream.to_device"]) == 1
+    assert threads["stream.queue_wait"] == threads["stream.to_device"]
+    assert threads["stream.collate"].isdisjoint(threads["stream.to_device"])
+
+
 # ------------------------------------------------------- streamed fit parity
 @pytest.mark.parametrize("use_kernel", [False, True])
 def test_streamed_fit_matches_eager_fit(use_kernel):

@@ -222,3 +222,46 @@ def test_dist_egnn_forward_compiles_on_four_chips(topo, chip):
     assert counts.get("edge_layout_host") and not counts.get(
         "edge_layout_regroup")
     assert not counts.get("edge_jnp") and not counts.get("virtual_jnp")
+
+
+def test_train_step_op_names_carry_kernel_passes_by_layer(chip):
+    """The compiled FastEGNN train step keeps, in its ``op_name`` metadata,
+    each kernel pass's ``pallas_call`` name under the scope of its layer
+    and pathway, and the loss and optimizer scopes: a profile of the chip
+    tells the passes and layers apart by them."""
+    import re
+
+    from repro.data.nbody import generate_nbody_dataset
+    from repro.pipeline import build_pipeline
+    from repro.training.trainer import TrainConfig, build_train_step
+
+    layers = 2
+    pipe = build_pipeline("fast_egnn", jax.random.PRNGKey(0),
+                          train_cfg=TrainConfig(lam_mmd=0.03),
+                          n_layers=layers, hidden=HID, h_in=1, n_virtual=3,
+                          s_dim=HID, use_kernel=True)
+    batch = pipe.make_batches(generate_nbody_dataset(2, n_nodes=24, seed=0),
+                              2)[0]
+    step, _ = build_train_step(pipe.apply_full, pipe.cfg, pipe.train_cfg,
+                               pipe.opt)
+    args = (pipe.params, pipe.opt.init(pipe.params), batch,
+            jax.random.PRNGKey(1))
+    text = step.lower(*_shapes(args, chip)).compile().as_text()
+    op_names = set(re.findall(r'op_name="([^"]*)"', text))
+    kernel_calls = [o for o in op_names if o.endswith("/pallas_call")]
+    passes = {"edge_pathway": ("edge_pathway_fused_fwd",
+                               "edge_pathway_bwd_fused_recv",
+                               "edge_pathway_bwd_fused_send"),
+              "virtual_pathway": ("virtual_pathway_fused_fwd",
+                                  "virtual_pathway_bwd_fused_grads")}
+    for k in range(layers):
+        for scope, names in passes.items():
+            for name in names:
+                pat = re.compile(rf"\blayer_{k}\)*/{scope}/.*/{name}/")
+                assert any(pat.search(o) for o in kernel_calls), (k, name)
+    for name in ("mmd_cross_sum_fwd", "mmd_cross_grads_bwd"):
+        assert any(re.search(rf"\bmmd_loss\)*/.*/{name}/", o)
+                   for o in kernel_calls), name
+    assert any("mse_loss" in o for o in op_names)
+    assert any(o.startswith("jit(train_step)/adam_update/")
+               for o in op_names)
