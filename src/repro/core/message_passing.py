@@ -140,9 +140,12 @@ def _scaled_rel(rel: Array, d2: Array, spec: EdgeSpec) -> Array:
 # Tests and the distributed benches assert the fused path actually
 # dispatched — and, when a host layout is supplied, that zero trace-time
 # regroups happened — instead of inferring it from the absence of errors.
-# Events: 'edge_kernel' / 'edge_jnp' (this module), 'virtual_kernel' /
-# 'virtual_jnp' (core.virtual_nodes), 'edge_layout_host' /
-# 'edge_layout_regroup' (kernels.edge_message).  Because jit caches traces,
+# Events: 'edge_kernel' / 'edge_jnp' (this module) and, with each
+# 'edge_kernel', the pieces of its one-hot products: 'edge_onehot_split3'
+# (f32 values as three exact bf16 pieces) or 'edge_onehot_bf16' (bf16
+# compute, one piece); 'virtual_kernel' / 'virtual_jnp'
+# (core.virtual_nodes), 'edge_layout_host' / 'edge_layout_regroup'
+# (kernels.edge_message).  Because jit caches traces,
 # counts reflect *traces*, not executions: reset before building a fresh
 # jitted program to observe its dispatch decisions.
 DISPATCH_COUNTS: dict[str, int] = {}
@@ -199,42 +202,46 @@ def edge_kernel_vmem_bytes(n_nodes: int, dh: int, h1: int, m: int,
 
     Largest of the forward and the two backward passes at the
     :func:`pick_windows` band sizes, counting what each pass keeps in
-    VMEM: the (block_e, ·) edge streams and the receiver-window blocks
-    double-buffered, the sender-window blocks (single-buffered in the
-    backward), the weights in the compute dtype plus f32 weight-gradient
-    accumulators, the two one-hots, eight (block_e, hidden) f32 edge
-    temporaries, and — for f32 compute — the bf16 pieces of the one-hots
-    that full-f32 contraction splits them into.  Calibrated against the
-    v5e compiler (``tests/test_tpu_compile.py``): at the default windows
-    hidden 256 compiles and 384 (f32) / 512 (bf16) does not, and this
-    model puts the 16 MiB budget between them.  All terms are
+    VMEM: the (block_e, ·) edge streams, the windows of the packed bf16
+    node operands (``edge_message.pack``: ``[h | x]`` on the sender side,
+    the backward's receiver side adds ``[inv | g_mh | g_dx]``), the packed
+    f32 accumulators, each double-buffered but for the backward's
+    single-buffered sender-window blocks; the weights in the compute
+    dtype, the f32 weight-gradient accumulators, and the two bf16 gather
+    one-hots.  Calibrated against the v5e compiler
+    (``tests/test_tpu_compile.py``): at the default windows hidden 256
+    (f32) / 512 (bf16) compiles and 320 (f32) / 576 (bf16) does not, and
+    this model puts the 16 MiB budget between them.  For the first kernel
+    it refuses of those two, the compiler reports 16.70 MiB (f32) and
+    17.13 MiB (bf16); this model's forward and pass A read 17.1 and
+    17.5 MiB there.  All terms are
     window-bounded — the model is independent of N once the windows
     saturate their defaults.
     """
-    from repro.kernels.edge_message import pick_windows
+    from repro.kernels.edge_message import LANE, onehot_pieces, pick_windows
     from repro.kernels.runtime import resolve_precision
 
     window, swindow, _ = pick_windows(n_nodes)
-    c = resolve_precision(precision).compute_dtype.itemsize
+    cdt = resolve_precision(precision).compute_dtype
+    c = cdt.itemsize
+    p = onehot_pieces(cdt)
     f = 4
     t = _tile_bytes
+    lanes = lambda width: -(-p * width // LANE) * LANE
     be, w, sw = block_e, window, swindow
     weights = ((dh, h1), (dh, h1), (1, h1), (1, h1), (h1, m), (1, m),
                (m, h1), (1, h1), (h1, 1))
     w_c = sum(t(r, k, c) for r, k in weights)
     w_f = sum(t(r, k, f) for r, k in weights)
-    edges = 2 * 3 * t(be, 1, 4)
-    one_hots = t(be, sw, c) + t(be, w, c)
-    if c == 4:
-        one_hots += 3 * (t(be, sw, 2) + t(be, w, 2))
-    edge_tmp = 8 * t(be, max(dh, h1, m), f)
-    common = edges + w_c + one_hots + edge_tmp
-    fwd = (common + 2 * (t(w, 3, c) + t(w, dh, c) + t(sw, 3, c) + t(sw, dh, c))
-           + 2 * (t(w, 3, f) + t(w, m, f) + t(w, 1, f)))
-    bwd_in = (common + 2 * (t(w, 3, f) + t(w, m, f) + t(w, 1, f) + t(w, 3, c)
-                            + t(w, dh, c)) + t(sw, 3, c) + t(sw, dh, c))
-    bwd_a = bwd_in + 2 * (t(w, 3, f) + t(w, dh, f)) + w_f
-    bwd_b = bwd_in + t(sw, 3, f) + t(sw, dh, f)
+    edges = 2 * (3 * t(be, 1, 4) + t(1, be, 4))
+    one_hots = t(be, sw, 2) + t(be, w, 2)
+    common = edges + w_c + one_hots
+    ks, kr = lanes(dh + 3), lanes(dh + 4 + m + 3)  # gathered: send, receive
+    kf, kb = lanes(m + 4), lanes(dh + 3)  # accumulated: fwd, bwd
+    fwd = common + 2 * (t(sw, ks, 2) + t(w, ks, 2) + t(w, kf, f))
+    bwd_in = common + t(sw, ks, 2) + 2 * t(w, kr, 2)
+    bwd_a = bwd_in + 2 * t(w, kb, f) + w_f
+    bwd_b = bwd_in + t(sw, kb, f)
     return max(fwd, bwd_a, bwd_b)
 
 
@@ -287,8 +294,13 @@ def edge_pathway(lp: dict, h: Array, x: Array, g: GeometricGraph,
     """
     if use_kernel and kernel_supported(lp, g, spec):
         from repro.kernels import ops as kops
+        from repro.kernels.edge_message import onehot_pieces
+        from repro.kernels.runtime import resolve_precision
 
         record_dispatch("edge_kernel")
+        pieces = onehot_pieces(resolve_precision(spec.precision).compute_dtype)
+        record_dispatch("edge_onehot_split3" if pieces == 3
+                        else "edge_onehot_bf16")
         dx, mh = kops.edge_pathway(lp, h, x, g, spec, layout=layout)
         return EdgePathwayOut(dx=dx if spec.gate != "none" else None, mh=mh)
     record_dispatch("edge_jnp")

@@ -36,10 +36,11 @@ every VMEM buffer by a *node window* instead of N:
     segment-sum formulation, now with N-independent VMEM;
   * the ``(block_e, hidden)`` messages, gates and edge vectors live only
     in VMEM registers: nothing of size O(E·hidden) ever touches HBM;
-  * output blocks (dx, mh, deg) are revisited only by the contiguous run
-    of their receiver window's edge blocks (TPU keeps a revisited output
-    block VMEM-resident across consecutive grid steps): the first block of
-    a window zeroes it, the last degree-normalises it.
+  * output blocks are revisited only by the contiguous run of their
+    window's edge blocks (TPU keeps a revisited output block
+    VMEM-resident across consecutive grid steps): the first block of a
+    window zeroes it.  They hold packed sums (below), recombined and
+    degree-normalised once after the grid.
 
 Eligibility is now a *VMEM budget* (``message_passing.kernel_supported``)
 computed from ``block_e``, the window sizes and the hidden dims — constant
@@ -89,10 +90,22 @@ loop: a profile tells the three passes apart by it.
 Precision contract
 ------------------
 Both directions take a static ``precision`` (``kernels.runtime.Precision``):
-operands are cast to ``precision.compute`` before every MXU matmul while
-``preferred_element_type=precision.accumulate`` keeps segment sums and
-weight-gradient accumulation wide.  The f32 default contracts at full f32
-precision (``_mm``); bf16 compute halves the streamed x/h bytes.
+operands are cast to ``precision.compute`` before every dense weight
+matmul while ``preferred_element_type=precision.accumulate`` keeps segment
+sums and weight-gradient accumulation wide.  The f32 default contracts the
+dense weight matmuls at full f32 precision (``_mm``).
+
+The one-hot gathers and scatters are exact at one bf16 MXU pass: a one-hot
+is exact in bf16, and a bf16 × bf16 product is exact in f32.  Under f32
+compute every gathered or scattered value travels as three bf16 pieces
+(:func:`split_pieces`, ``hi + mid + lo``), laid side by side in one
+lane-dense operand (:func:`pack`): the node operands once per call in
+XLA, the per-edge values per block in the kernel.  A gather returns each
+value bit for bit; a scatter rounds only in its f32 accumulation.  The
+split is exact for every finite f32 of magnitude 2**-103 or more (and
+zero); below that a piece can be subnormal, and arithmetic that flushes
+subnormals (XLA's) drops it.  Under bf16 compute a value is one piece,
+itself rounded to bf16 (:func:`onehot_pieces`).
 """
 from __future__ import annotations
 
@@ -271,11 +284,14 @@ def banded_layout(snd: Array, rcv: Array, em: Array, *, n_pad: int,
 
 
 def _mm(a: Array, b: Array, *, cdt, adt) -> Array:
-    """The precision-contract matmul: compute-dtype operands, wide result.
+    """The precision-contract matmul of the dense weights: compute-dtype
+    operands, wide result.
 
     f32 compute asks Mosaic for full f32 contraction.  At its default
     precision an f32 dot runs as a bf16 pass: on a v5e a one-hot gather of
     unit-range values was then off by 2e-3, about 3 significant digits.
+    The one-hot products do not go through here: they move exact bf16
+    pieces (:func:`pack`).
     """
     prec = jax.lax.Precision.HIGHEST if cdt == jnp.float32 else None
     return jnp.matmul(a.astype(cdt), b.astype(cdt), preferred_element_type=adt,
@@ -287,58 +303,127 @@ def _silu_grad(u: Array) -> Array:
     return s * (1.0 + u * (1.0 - s))
 
 
+# ------------------------------------------------ exact one-hot products
+def onehot_pieces(compute_dtype) -> int:
+    """bf16 pieces each value splits into for the one-hot products.
+
+    Three under f32 compute: ``hi + mid + lo`` (:func:`split_pieces`) sums
+    back to the value exactly for every finite f32 of magnitude 2**-103 or
+    more (module docstring).  One under bf16 compute, whose operands
+    already are bf16.
+    """
+    return 3 if jnp.dtype(compute_dtype) == jnp.float32 else 1
+
+
+#: the sign, exponent and top 7 mantissa bits of an f32: a bf16's bits
+_BF16_BITS = -(1 << 16)
+
+
+def split_pieces(v: Array, pieces: int) -> list[Array]:
+    """``v`` as ``pieces`` bf16 arrays: ``hi = trunc(v)``, ``mid = trunc(v −
+    hi)``, ``lo = v − hi − mid``, where ``trunc`` keeps an f32's top 16
+    bits (a bf16, exactly) and every difference is exact in f32.
+
+    The pieces come from the bits, not from a rounding ``v →
+    bf16 → f32`` round trip, which a compiler that allows excess precision
+    may fold to ``v`` (XLA on the TPU does, leaving ``mid = lo = 0``).
+    """
+    out, rest = [], v.astype(jnp.float32)
+    for k in range(pieces):
+        p = rest
+        if k < pieces - 1:
+            p = jax.lax.bitcast_convert_type(
+                jax.lax.bitcast_convert_type(rest, jnp.int32) & _BF16_BITS,
+                jnp.float32)
+            rest = rest - p
+        out.append(p.astype(jnp.bfloat16))
+    return out
+
+
+def pack(cols: list[Array], pieces: int) -> Array:
+    """``cols`` side by side, split into bf16 pieces: (rows, width) values
+    → (rows, lanes) bf16 with piece k at lanes [k·width, (k+1)·width) and
+    zeros up to a whole number of lane tiles."""
+    v = jnp.concatenate([c.astype(jnp.float32) for c in cols], axis=-1)
+    width = v.shape[-1]
+    ps = split_pieces(v, pieces)
+    pad = _round_up(pieces * width, LANE) - pieces * width
+    if pad:
+        ps.append(jnp.zeros(v.shape[:-1] + (pad,), jnp.bfloat16))
+    return jnp.concatenate(ps, axis=-1)
+
+
+def unpack(g: Array, width: int, pieces: int) -> Array:
+    """Recombine the pieces of a product with a :func:`pack`-ed operand:
+    (rows, lanes) → (rows, width), ``(hi + mid) + lo``."""
+    out = g[:, :width]
+    for k in range(1, pieces):
+        out = out + g[:, k * width:(k + 1) * width]
+    return out
+
+
+def _gather(ids: Array, packed: Array, width: int, pieces: int,
+            adt) -> Array:
+    """Rows ``ids`` (BE, 1) of a window of a :func:`pack`-ed array: one
+    bf16 one-hot matmul, exact — each output element is one piece times 1
+    plus zeros."""
+    oh = (ids == jax.lax.broadcasted_iota(
+        jnp.int32, (ids.shape[0], packed.shape[0]), 1)).astype(jnp.bfloat16)
+    return unpack(jnp.dot(oh, packed, preferred_element_type=adt), width,
+                  pieces)
+
+
+def _scatter(ids_row: Array, cols: list[Array], rows: int, pieces: int,
+             adt) -> Array:
+    """Sums of the edge values ``cols`` into a ``rows``-row window by the
+    window-local ids ``ids_row`` (1, BE): the one-hot is built transposed,
+    (rows, BE), from the lane-major ids.  Returns the packed sums; the
+    pieces are recombined once, after the grid (:func:`unpack`)."""
+    oh_t = (ids_row == jax.lax.broadcasted_iota(
+        jnp.int32, (rows, ids_row.shape[1]), 0)).astype(jnp.bfloat16)
+    return jnp.dot(oh_t, pack(cols, pieces), preferred_element_type=adt)
+
+
 def _edge_kernel(
     rwin_ref, swin_ref,  # scalar-prefetched (n_blocks,) window coords
-    snd_ref, rcv_ref, em_ref, xr_ref, hr_ref, xs_ref, hs_ref,
+    snd_ref, rcv_ref, rcvt_ref, em_ref, xhr_ref, xhs_ref,
     w1r_ref, w1s_ref, w1d_ref, b1_ref, w2_ref, b2_ref,
     wg1_ref, bg1_ref, wg2_ref,
-    dx_ref, mh_ref, deg_ref,
+    acc_ref,
     *, gate_mode: str, rel_mode: str, clamp: float, compute: str, accum: str,
 ):
     b = pl.program_id(0)
-    nb = pl.num_programs(0)
     rwb = rwin_ref[b]
     rw_prev = jnp.where(b > 0, rwin_ref[jnp.maximum(b - 1, 0)], -1)
-    rw_next = jnp.where(b < nb - 1, rwin_ref[jnp.minimum(b + 1, nb - 1)], -1)
-    cdt = jnp.dtype(compute)
-    mm = functools.partial(_mm, cdt=cdt, adt=jnp.dtype(accum))
+    cdt, adt = jnp.dtype(compute), jnp.dtype(accum)
+    mm = functools.partial(_mm, cdt=cdt, adt=adt)
+    pieces = onehot_pieces(cdt)
+    dh = w1r_ref.shape[0]
 
     @pl.when(rwb != rw_prev)  # first block of this receiver window
     def _init():
-        dx_ref[...] = jnp.zeros_like(dx_ref)
-        mh_ref[...] = jnp.zeros_like(mh_ref)
-        deg_ref[...] = jnp.zeros_like(deg_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    snd = snd_ref[...]  # (BE, 1) int32, sender-window-local
-    rcv = rcv_ref[...]  # (BE, 1) int32, receiver-window-local
     em = em_ref[...]  # (BE, 1)
-    be = snd.shape[0]
-    sw = xs_ref.shape[0]
-    w = xr_ref.shape[0]
-    # Banded one-hot gather/scatter operands (MXU-native segment ops):
-    # (BE, swindow) against the sender window, (BE, window) against the
-    # receiver window — VMEM cost independent of N.  Masked slots carry
-    # local index 0: they gather finite garbage and scatter em=0 ⇒ no-ops.
-    oh_s = (snd == jax.lax.broadcasted_iota(jnp.int32, (be, sw), 1)).astype(cdt)
-    oh_r = (rcv == jax.lax.broadcasted_iota(jnp.int32, (be, w), 1)).astype(cdt)
-
-    xs = mm(oh_s, xs_ref[...])  # (BE, 3) endpoint gathers, accumulate dtype
-    xr = mm(oh_r, xr_ref[...])
-    rel = xr - xs
+    # Banded one-hot gathers (MXU-native segment ops): (BE, swindow)
+    # against the sender window of packed [h | x], (BE, window) against
+    # the receiver window — VMEM cost independent of N.  Masked slots
+    # carry local index 0: they gather finite garbage and scatter em=0.
+    hxs = _gather(snd_ref[...], xhs_ref[...], dh + 3, pieces, adt)
+    hxr = _gather(rcv_ref[...], xhr_ref[...], dh + 3, pieces, adt)
+    rel = hxr[:, dh:] - hxs[:, dh:]
     d2 = jnp.sum(rel * rel, axis=-1, keepdims=True)  # (BE, 1)
 
     # φ1 layer 1 over [h_r | h_s | d²] with the weight matrix pre-split by
     # input slice; zero-width/zero-weight slices fall out as no-ops.
     t1 = jax.nn.silu(
-        mm(mm(oh_r, hr_ref[...]), w1r_ref[...])
-        + mm(mm(oh_s, hs_ref[...]), w1s_ref[...])
+        mm(hxr[:, :dh], w1r_ref[...])
+        + mm(hxs[:, :dh], w1s_ref[...])
         + mm(d2, w1d_ref[...])
         + b1_ref[...]
     )
     msg = mm(t1, w2_ref[...]) + b2_ref[...]  # (BE, M) — never written to HBM
-
-    mh_ref[...] += mm(oh_r.T, msg * em).astype(mh_ref.dtype)
-    deg_ref[...] += mm(oh_r.T, em).astype(deg_ref.dtype)
+    out = [msg * em, em]
 
     if gate_mode != "none":
         if gate_mode == "mlp":
@@ -349,26 +434,22 @@ def _edge_kernel(
         gate = jnp.clip(gate, -clamp, clamp)
         if rel_mode == "inv1p":
             rel = rel / (jnp.sqrt(d2 + 1e-12) + 1.0)
-        dx_ref[...] += mm(oh_r.T, rel * gate * em).astype(dx_ref.dtype)
-
-    @pl.when(rwb != rw_next)  # last block of this receiver window
-    def _normalize():
-        inv = 1.0 / jnp.maximum(deg_ref[...], 1.0)  # (window, 1)
-        mh_ref[...] = mh_ref[...] * inv
-        if gate_mode != "none":
-            dx_ref[...] = dx_ref[...] * inv
+        out.append(rel * gate * em)
+    # packed [Σ msg·em | Σ em | Σ rel·gate·em] of the receiver window
+    acc_ref[...] += _scatter(rcvt_ref[...], out, acc_ref.shape[0], pieces,
+                             adt)
 
 
 def _resolve_banded(x, h, snd, rcv, em, *, n, block_e, window, swindow,
                     layout, record: str | None):
     """Shared fwd/bwd banding step: host layout or trace-time regroup.
 
-    Returns ``(snd2, rcv2, em2, block_rwin, block_swin, n_blocks, x, h,
-    n_pad, window, swindow)`` with x/h zero-padded to ``n_pad`` rows and
-    the per-slot endpoints window-localised.  ``record`` names the dispatch
-    event to log (None on the backward — the forward already recorded the
-    pair's layout provenance, and double counts would skew the telemetry
-    the regroup gates assert on).
+    Returns ``(snd_loc, rcv_loc, em_b, block_rwin, block_swin, n_blocks,
+    x, h, n_pad, window, swindow)`` with x/h zero-padded to ``n_pad`` rows
+    and the per-slot endpoints window-localised, all (cap,).  ``record``
+    names the dispatch event to log (None on the backward — the forward
+    already recorded the pair's layout provenance, and double counts would
+    skew the telemetry the regroup gates assert on).
     """
     window, swindow, n_pad = pick_windows(n, window=window, swindow=swindow)
     if layout is not None:
@@ -410,8 +491,8 @@ def _resolve_banded(x, h, snd, rcv, em, *, n, block_e, window, swindow,
         pad = n_pad - n
         x = jnp.pad(x, ((0, pad), (0, 0)))
         h = jnp.pad(h, ((0, pad), (0, 0)))
-    return (snd_loc[:, None], rcv_loc[:, None], em_b[:, None], block_rwin,
-            block_swin, n_blocks, x, h, n_pad, window, swindow)
+    return (snd_loc, rcv_loc, em_b, block_rwin, block_swin, n_blocks, x, h,
+            n_pad, window, swindow)
 
 
 @functools.partial(
@@ -463,24 +544,24 @@ def edge_pathway_fused(
     if e == 0:  # empty graph: nothing to reduce (edge-drop p=1.0 story)
         return (jnp.zeros((n, 3), out_dt), jnp.zeros((n, m), out_dt),
                 jnp.zeros((n, 1), out_dt))
-    (snd2, rcv2, em2, block_rwin, block_swin, n_blocks, x, h, n_pad,
+    (snd_loc, rcv_loc, em_b, block_rwin, block_swin, n_blocks, x, h, n_pad,
      window, swindow) = _resolve_banded(
         x, h, snd, rcv, em, n=n, block_e=block_e, window=window,
         swindow=swindow, layout=layout, record="fwd")
-    em2 = em2.astype(out_dt)
-    cdt = prec.compute_dtype
-    # cast the streamed node operands + weights once at the boundary: in
-    # bf16 mode this halves the windowed x/h DMA bytes per block
-    x, h = x.astype(cdt), h.astype(cdt)
+    cdt, adt = prec.compute_dtype, prec.accumulate_dtype
+    pieces = onehot_pieces(cdt)
+    # the node operands, cast to the compute dtype and packed once into
+    # bf16 pieces: one (n_pad, lanes) array both endpoints gather from
+    xh = pack([h.astype(cdt), x.astype(cdt)], pieces)
     ws = tuple(a.astype(cdt) for a in (w1r, w1s, w1d, b1, w2, b2,
                                        wg1, bg1, wg2))
-    dh = h.shape[1]
+    width = m + 1 + (3 if gate_mode != "none" else 0)  # [msg | em | dx]
+    lanes = _round_up(pieces * width, LANE)
     full = lambda a: pl.BlockSpec(a.shape, lambda b, rw, sw: (0,) * a.ndim)
     eblk = pl.BlockSpec((block_e, 1), lambda b, rw, sw: (b, 0))
-    rblk = lambda width: pl.BlockSpec((window, width),
-                                      lambda b, rw, sw: (rw[b], 0))
-    sblk = lambda width: pl.BlockSpec((swindow, width),
-                                      lambda b, rw, sw: (sw[b], 0))
+    erow = pl.BlockSpec((1, block_e), lambda b, rw, sw: (0, b))
+    rblk = lambda k: pl.BlockSpec((window, k), lambda b, rw, sw: (rw[b], 0))
+    sblk = lambda k: pl.BlockSpec((swindow, k), lambda b, rw, sw: (sw[b], 0))
 
     kernel = functools.partial(_edge_kernel, gate_mode=gate_mode,
                                rel_mode=rel_mode, clamp=clamp,
@@ -489,36 +570,40 @@ def edge_pathway_fused(
         num_scalar_prefetch=2,
         grid=(n_blocks,),
         in_specs=[
-            eblk, eblk, eblk,
-            rblk(3), rblk(dh), sblk(3), sblk(dh),
+            eblk, eblk, erow, eblk,
+            rblk(xh.shape[1]), sblk(xh.shape[1]),
             full(ws[0]), full(ws[1]), full(ws[2]), full(ws[3]), full(ws[4]),
             full(ws[5]), full(ws[6]), full(ws[7]), full(ws[8]),
         ],
-        out_specs=(rblk(3), rblk(m), rblk(1)),
+        out_specs=rblk(lanes),
     )
-    dx, mh, deg = pl.pallas_call(
+    acc = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         name="edge_pathway_fused_fwd",
-        out_shape=(
-            jax.ShapeDtypeStruct((n_pad, 3), out_dt),
-            jax.ShapeDtypeStruct((n_pad, m), out_dt),
-            jax.ShapeDtypeStruct((n_pad, 1), out_dt),
-        ),
+        out_shape=jax.ShapeDtypeStruct((n_pad, lanes), adt),
         interpret=interpret,
         compiler_params=_compiler_params(),
-    )(block_rwin, block_swin, snd2, rcv2, em2, x, h, x, h, *ws)
-    return dx[:n], mh[:n], deg[:n]
+    )(block_rwin, block_swin, snd_loc[:, None], rcv_loc[:, None],
+      rcv_loc[None, :], em_b.astype(adt)[:, None], xh, xh, *ws)
+    sums = unpack(acc[:n], width, pieces)
+    deg = sums[:, m:m + 1]
+    inv = 1.0 / jnp.maximum(deg, 1.0)
+    mh = sums[:, :m] * inv
+    dx = sums[:, m + 1:] * inv if gate_mode != "none" else jnp.zeros((n, 3))
+    return dx.astype(out_dt), mh.astype(out_dt), deg.astype(out_dt)
 
 
 # ------------------------------------------------------------ fused backward
-def _edge_bwd_common(oh_s, oh_r, em, gdx_w, gmh_w, inv_w, xr_w, hr_w, xs_w,
-                     hs_w, w1r, w1s, w1d, b1, w2, b2, wg1, bg1, wg2, mm,
-                     gate_mode: str, rel_mode: str, clamp: float) -> dict:
+def _edge_bwd_common(snd, rcv, em, s_win, r_win, w1r, w1s, w1d, b1, w2, b2,
+                     wg1, bg1, wg2, mm, pieces: int, adt, gate_mode: str,
+                     rel_mode: str, clamp: float) -> dict:
     """Per-block recompute + upstream backprop shared by both bwd passes.
 
-    Recomputes the forward chain (messages, gates, pre-activations) for one
-    banded edge block entirely in VMEM, then backpropagates the gathered
+    Gathers the block's endpoints from the packed sender window ``s_win``
+    (``[h | x]``) and receiver window ``r_win`` (``[h | x | inv | g_mh |
+    g_dx]``), recomputes the forward chain (messages, gates,
+    pre-activations) entirely in VMEM, then backpropagates the gathered
     output cotangents down to the per-edge quantities both passes scatter:
     ``g_pre1`` (E-block × H1 — the φ1 layer-1 cotangent, source of every
     dh and weight grad) and ``g_rel_tot`` (E-block × 3 — the total
@@ -526,22 +611,24 @@ def _edge_bwd_common(oh_s, oh_r, em, gdx_w, gmh_w, inv_w, xr_w, hr_w, xs_w,
     mask are folded into the upstream here, so masked slots (which gather
     window-local index 0) produce exact zeros throughout.
     """
-    xs = mm(oh_s, xs_w)
-    xr = mm(oh_r, xr_w)
-    hr_e = mm(oh_r, hr_w)
-    hs_e = mm(oh_s, hs_w)
+    dh, m = w1r.shape[0], w2.shape[1]
+    gated = gate_mode != "none"
+    hxs = _gather(snd, s_win, dh + 3, pieces, adt)
+    rr = _gather(rcv, r_win, dh + 4 + m + (3 if gated else 0), pieces, adt)
+    hs_e, xs = hxs[:, :dh], hxs[:, dh:]
+    hr_e, xr = rr[:, :dh], rr[:, dh:dh + 3]
     rel = xr - xs
     d2 = jnp.sum(rel * rel, axis=-1, keepdims=True)
     pre1 = mm(hr_e, w1r) + mm(hs_e, w1s) + mm(d2, w1d) + b1
     t1 = jax.nn.silu(pre1)
     msg = mm(t1, w2) + b2
-    scale = mm(oh_r, inv_w) * em  # per-edge upstream factor inv[r]·em
-    g_msg = mm(oh_r, gmh_w) * scale
+    scale = rr[:, dh + 3:dh + 4] * em  # per-edge upstream factor inv[r]·em
+    g_msg = rr[:, dh + 4:dh + 4 + m] * scale
     g_rel = jnp.zeros_like(rel)
     g_d2 = jnp.zeros_like(d2)
     out = {}
-    if gate_mode != "none":
-        p = mm(oh_r, gdx_w) * scale  # (BE, 3) cotangent of rel_used·gate
+    if gated:
+        p = rr[:, dh + 4 + m:] * scale  # (BE, 3) cotangent of rel_used·gate
         if gate_mode == "mlp":
             gp1 = mm(msg, wg1) + bg1
             gt = jax.nn.silu(gp1)
@@ -581,27 +668,28 @@ def _edge_bwd_common(oh_s, oh_r, em, gdx_w, gmh_w, inv_w, xr_w, hr_w, xs_w,
 
 def _edge_bwd_r_kernel(
     rwin_ref, swin_ref,
-    snd_ref, rcv_ref, em_ref,
-    gdx_ref, gmh_ref, inv_ref, xr_ref, hr_ref, xs_ref, hs_ref,
+    snd_ref, rcv_ref, rcvt_ref, em_ref, r_ref, s_ref,
     w1r_ref, w1s_ref, w1d_ref, b1_ref, w2_ref, b2_ref,
     wg1_ref, bg1_ref, wg2_ref,
-    dxr_ref, dhr_ref,
+    accr_ref,
     dw1r_ref, dw1s_ref, dw1d_ref, db1_ref, dw2_ref, db2_ref,
     dwg1_ref, dbg1_ref, dwg2_ref,
     *, gate_mode: str, rel_mode: str, clamp: float, compute: str, accum: str,
 ):
     """Receiver-major backward pass: forward's block order, so receiver
     windows form contiguous runs — accumulates the receiver-endpoint x/h
-    gradients per window and every weight gradient across the whole grid."""
+    gradients per window (packed ``[dh | dx]`` sums) and every weight
+    gradient across the whole grid."""
     b = pl.program_id(0)
     rwb = rwin_ref[b]
     rw_prev = jnp.where(b > 0, rwin_ref[jnp.maximum(b - 1, 0)], -1)
-    mm = functools.partial(_mm, cdt=jnp.dtype(compute), adt=jnp.dtype(accum))
+    cdt, adt = jnp.dtype(compute), jnp.dtype(accum)
+    mm = functools.partial(_mm, cdt=cdt, adt=adt)
+    pieces = onehot_pieces(cdt)
 
     @pl.when(rwb != rw_prev)  # first block of this receiver window
     def _init_window():
-        dxr_ref[...] = jnp.zeros_like(dxr_ref)
-        dhr_ref[...] = jnp.zeros_like(dhr_ref)
+        accr_ref[...] = jnp.zeros_like(accr_ref)
 
     @pl.when(b == 0)  # weight grads accumulate over the entire grid
     def _init_weight_grads():
@@ -609,23 +697,15 @@ def _edge_bwd_r_kernel(
                   dwg1_ref, dbg1_ref, dwg2_ref):
             r[...] = jnp.zeros_like(r)
 
-    snd = snd_ref[...]
-    rcv = rcv_ref[...]
-    em = em_ref[...]
-    be = snd.shape[0]
-    cdt = jnp.dtype(compute)
-    oh_s = (snd == jax.lax.broadcasted_iota(jnp.int32, (be, xs_ref.shape[0]),
-                                            1)).astype(cdt)
-    oh_r = (rcv == jax.lax.broadcasted_iota(jnp.int32, (be, xr_ref.shape[0]),
-                                            1)).astype(cdt)
     c = _edge_bwd_common(
-        oh_s, oh_r, em, gdx_ref[...], gmh_ref[...], inv_ref[...],
-        xr_ref[...], hr_ref[...], xs_ref[...], hs_ref[...],
+        snd_ref[...], rcv_ref[...], em_ref[...], s_ref[...], r_ref[...],
         w1r_ref[...], w1s_ref[...], w1d_ref[...], b1_ref[...], w2_ref[...],
-        b2_ref[...], wg1_ref[...], bg1_ref[...], wg2_ref[...], mm,
-        gate_mode, rel_mode, clamp)
-    dxr_ref[...] += mm(oh_r.T, c["g_rel_tot"])  # dL/dx_r += +g_rel
-    dhr_ref[...] += mm(oh_r.T, mm(c["g_pre1"], w1r_ref[...].T))
+        b2_ref[...], wg1_ref[...], bg1_ref[...], wg2_ref[...], mm, pieces,
+        adt, gate_mode, rel_mode, clamp)
+    # dL/dh_r, dL/dx_r += +g_rel
+    accr_ref[...] += _scatter(
+        rcvt_ref[...], [mm(c["g_pre1"], w1r_ref[...].T), c["g_rel_tot"]],
+        accr_ref.shape[0], pieces, adt)
     dw1r_ref[...] += mm(c["hr_e"].T, c["g_pre1"])
     dw1s_ref[...] += mm(c["hs_e"].T, c["g_pre1"])
     dw1d_ref[...] += mm(c["d2"].T, c["g_pre1"])
@@ -640,45 +720,38 @@ def _edge_bwd_r_kernel(
 
 def _edge_bwd_s_kernel(
     perm_ref, rwp_ref, swp_ref,
-    snd_ref, rcv_ref, em_ref,
-    gdx_ref, gmh_ref, inv_ref, xr_ref, hr_ref, xs_ref, hs_ref,
+    snd_ref, rcv_ref, sndt_ref, em_ref, r_ref, s_ref,
     w1r_ref, w1s_ref, w1d_ref, b1_ref, w2_ref, b2_ref,
     wg1_ref, bg1_ref, wg2_ref,
-    dxs_ref, dhs_ref,
+    accs_ref,
     *, gate_mode: str, rel_mode: str, clamp: float, compute: str, accum: str,
 ):
     """Sender-major backward pass: the same blocks in ``argsort(block_swin)``
     order (``perm`` scalar-prefetched into every index map), so sender
     windows form contiguous runs and the sender-endpoint x/h gradients
-    accumulate with the standard init-on-first-block discipline."""
-    del perm_ref  # consumed by the BlockSpec index maps only
+    accumulate (packed ``[dh | dx]`` sums) with the standard
+    init-on-first-block discipline."""
+    del perm_ref, rwp_ref  # consumed by the BlockSpec index maps only
     j = pl.program_id(0)
     swb = swp_ref[j]
     sw_prev = jnp.where(j > 0, swp_ref[jnp.maximum(j - 1, 0)], -1)
-    mm = functools.partial(_mm, cdt=jnp.dtype(compute), adt=jnp.dtype(accum))
+    cdt, adt = jnp.dtype(compute), jnp.dtype(accum)
+    mm = functools.partial(_mm, cdt=cdt, adt=adt)
+    pieces = onehot_pieces(cdt)
 
     @pl.when(swb != sw_prev)  # first block of this sender window
     def _init_window():
-        dxs_ref[...] = jnp.zeros_like(dxs_ref)
-        dhs_ref[...] = jnp.zeros_like(dhs_ref)
+        accs_ref[...] = jnp.zeros_like(accs_ref)
 
-    snd = snd_ref[...]
-    rcv = rcv_ref[...]
-    em = em_ref[...]
-    be = snd.shape[0]
-    cdt = jnp.dtype(compute)
-    oh_s = (snd == jax.lax.broadcasted_iota(jnp.int32, (be, xs_ref.shape[0]),
-                                            1)).astype(cdt)
-    oh_r = (rcv == jax.lax.broadcasted_iota(jnp.int32, (be, xr_ref.shape[0]),
-                                            1)).astype(cdt)
     c = _edge_bwd_common(
-        oh_s, oh_r, em, gdx_ref[...], gmh_ref[...], inv_ref[...],
-        xr_ref[...], hr_ref[...], xs_ref[...], hs_ref[...],
+        snd_ref[...], rcv_ref[...], em_ref[...], s_ref[...], r_ref[...],
         w1r_ref[...], w1s_ref[...], w1d_ref[...], b1_ref[...], w2_ref[...],
-        b2_ref[...], wg1_ref[...], bg1_ref[...], wg2_ref[...], mm,
-        gate_mode, rel_mode, clamp)
-    dxs_ref[...] += mm(oh_s.T, -c["g_rel_tot"])  # dL/dx_s −= g_rel
-    dhs_ref[...] += mm(oh_s.T, mm(c["g_pre1"], w1s_ref[...].T))
+        b2_ref[...], wg1_ref[...], bg1_ref[...], wg2_ref[...], mm, pieces,
+        adt, gate_mode, rel_mode, clamp)
+    # dL/dh_s, dL/dx_s −= g_rel
+    accs_ref[...] += _scatter(
+        sndt_ref[...], [mm(c["g_pre1"], w1s_ref[...].T), -c["g_rel_tot"]],
+        accs_ref.shape[0], pieces, adt)
 
 
 @functools.partial(
@@ -724,11 +797,12 @@ def edge_pathway_bwd_fused(
     if e == 0:
         return tuple(jnp.zeros(a.shape, adt) for a in ((x, h) + weights))
     m = w2.shape[1]
-    (snd2, rcv2, em2, block_rwin, block_swin, n_blocks, x, h, n_pad,
+    (snd_loc, rcv_loc, em_b, block_rwin, block_swin, n_blocks, x, h, n_pad,
      window, swindow) = _resolve_banded(
         x, h, snd, rcv, em, n=n, block_e=block_e, window=window,
         swindow=swindow, layout=layout, record=None)
-    em2 = em2.astype(adt)
+    snd2, rcv2 = snd_loc[:, None], rcv_loc[:, None]
+    em2 = em_b.astype(adt)[:, None]
     pad = n_pad - n
     g_dx = jnp.pad(g_dx.astype(adt), ((0, pad), (0, 0)))
     g_mh = jnp.pad(g_mh.astype(adt), ((0, pad), (0, 0)))
@@ -738,6 +812,13 @@ def edge_pathway_bwd_fused(
     x, h = x.astype(cdt), h.astype(cdt)
     ws = tuple(a.astype(cdt) for a in weights)
     dh = h.shape[1]
+    pieces = onehot_pieces(cdt)
+    # what each endpoint gathers, packed once into bf16 pieces: senders
+    # [h | x], receivers [h | x | inv | g_mh | g_dx]
+    s_pack = pack([h, x], pieces)
+    r_pack = pack([h, x, inv, g_mh] + ([g_dx] if gate_mode != "none" else []),
+                  pieces)
+    lanes = _round_up(pieces * (dh + 3), LANE)  # packed [dh | dx] sums
 
     kw = dict(gate_mode=gate_mode, rel_mode=rel_mode, clamp=clamp,
               compute=prec.compute, accum=prec.accumulate)
@@ -746,39 +827,36 @@ def edge_pathway_bwd_fused(
     # ---- pass A: receiver-major (dx_r, dh_r, all weight grads) ----------
     full = lambda a: pl.BlockSpec(a.shape, lambda b, rw, sw: (0,) * a.ndim)
     eblk = pl.BlockSpec((block_e, 1), lambda b, rw, sw: (b, 0))
-    rblk = lambda width: pl.BlockSpec((window, width),
-                                      lambda b, rw, sw: (rw[b], 0))
+    erow = pl.BlockSpec((1, block_e), lambda b, rw, sw: (0, b))
+    rblk = lambda k: pl.BlockSpec((window, k), lambda b, rw, sw: (rw[b], 0))
     # sender-window blocks are single-buffered in both backward passes:
     # their double buffers are the largest term of the VMEM budget, and
     # the window changes only at band boundaries
-    sblk = lambda width: pl.BlockSpec((swindow, width),
-                                      lambda b, rw, sw: (sw[b], 0),
-                                      pipeline_mode=pl.Buffered(1))
+    sblk = lambda k: pl.BlockSpec((swindow, k), lambda b, rw, sw: (sw[b], 0),
+                                  pipeline_mode=pl.Buffered(1))
     grid_a = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(n_blocks,),
         in_specs=[
-            eblk, eblk, eblk,
-            rblk(3), rblk(m), rblk(1), rblk(3), rblk(dh),
-            sblk(3), sblk(dh),
+            eblk, eblk, erow, eblk,
+            rblk(r_pack.shape[1]), sblk(s_pack.shape[1]),
             full(ws[0]), full(ws[1]), full(ws[2]), full(ws[3]), full(ws[4]),
             full(ws[5]), full(ws[6]), full(ws[7]), full(ws[8]),
         ],
-        out_specs=(rblk(3), rblk(dh),
+        out_specs=(rblk(lanes),
                    full(ws[0]), full(ws[1]), full(ws[2]), full(ws[3]),
                    full(ws[4]), full(ws[5]), full(ws[6]), full(ws[7]),
                    full(ws[8])),
     )
-    dxr, dhr, *gws = pl.pallas_call(
+    acc_r, *gws = pl.pallas_call(
         functools.partial(_edge_bwd_r_kernel, **kw),
         grid_spec=grid_a,
         name="edge_pathway_bwd_fused_recv",
-        out_shape=(f((n_pad, 3)), f((n_pad, dh)))
-        + tuple(f(a.shape) for a in weights),
+        out_shape=(f((n_pad, lanes)),) + tuple(f(a.shape) for a in weights),
         interpret=interpret,
         compiler_params=_compiler_params(),
-    )(block_rwin, block_swin, snd2, rcv2, em2,
-      g_dx, g_mh, inv, x, h, x, h, *ws)
+    )(block_rwin, block_swin, snd2, rcv2, rcv_loc[None, :], em2,
+      r_pack, s_pack, *ws)
 
     # ---- pass B: sender-major over the block permutation (dx_s, dh_s) ---
     perm = jnp.argsort(block_swin, stable=True).astype(jnp.int32)
@@ -787,38 +865,37 @@ def edge_pathway_bwd_fused(
     full_p = lambda a: pl.BlockSpec(a.shape,
                                     lambda j, pm, rp, sp: (0,) * a.ndim)
     eblk_p = pl.BlockSpec((block_e, 1), lambda j, pm, rp, sp: (pm[j], 0))
-    rblk_p = lambda width: pl.BlockSpec((window, width),
-                                        lambda j, pm, rp, sp: (rp[j], 0))
-    sblk_p = lambda width: pl.BlockSpec((swindow, width),
-                                        lambda j, pm, rp, sp: (sp[j], 0),
-                                        pipeline_mode=pl.Buffered(1))
+    erow_p = pl.BlockSpec((1, block_e), lambda j, pm, rp, sp: (0, pm[j]))
+    rblk_p = lambda k: pl.BlockSpec((window, k),
+                                    lambda j, pm, rp, sp: (rp[j], 0))
+    sblk_p = lambda k: pl.BlockSpec((swindow, k),
+                                    lambda j, pm, rp, sp: (sp[j], 0),
+                                    pipeline_mode=pl.Buffered(1))
     grid_b = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(n_blocks,),
         in_specs=[
-            eblk_p, eblk_p, eblk_p,
-            rblk_p(3), rblk_p(m), rblk_p(1), rblk_p(3), rblk_p(dh),
-            sblk_p(3), sblk_p(dh),
+            eblk_p, eblk_p, erow_p, eblk_p,
+            rblk_p(r_pack.shape[1]), sblk_p(s_pack.shape[1]),
             full_p(ws[0]), full_p(ws[1]), full_p(ws[2]), full_p(ws[3]),
             full_p(ws[4]), full_p(ws[5]), full_p(ws[6]), full_p(ws[7]),
             full_p(ws[8]),
         ],
-        out_specs=(sblk_p(3), sblk_p(dh)),
+        out_specs=sblk_p(lanes),
     )
-    dxs, dhs = pl.pallas_call(
+    acc_s = pl.pallas_call(
         functools.partial(_edge_bwd_s_kernel, **kw),
         grid_spec=grid_b,
         name="edge_pathway_bwd_fused_send",
-        out_shape=(f((n_pad, 3)), f((n_pad, dh))),
+        out_shape=f((n_pad, lanes)),
         interpret=interpret,
         compiler_params=_compiler_params(),
-    )(perm, rw_p, sw_p, snd2, rcv2, em2,
-      g_dx, g_mh, inv, x, h, x, h, *ws)
+    )(perm, rw_p, sw_p, snd2, rcv2, snd_loc[None, :], em2,
+      r_pack, s_pack, *ws)
     # sender windows no block gathers from are never visited → mask, don't
     # trust their (uninitialised) output blocks
     nsw = n_pad // swindow
-    visited = jnp.zeros((nsw,), adt).at[block_swin].set(1.0)
-    vmask = jnp.repeat(visited, swindow)[:, None]
-    gx = dxr[:n] + (dxs * vmask)[:n]
-    gh = dhr[:n] + (dhs * vmask)[:n]
-    return (gx, gh, *gws)
+    visited = jnp.zeros((nsw,), bool).at[block_swin].set(True)
+    acc_s = jnp.where(jnp.repeat(visited, swindow)[:, None], acc_s, 0.0)
+    g = unpack(acc_r[:n], dh + 3, pieces) + unpack(acc_s[:n], dh + 3, pieces)
+    return (g[:, dh:], g[:, :dh], *gws)

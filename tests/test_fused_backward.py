@@ -16,6 +16,7 @@ covers the PR's fused-backward contract (DESIGN.md §9):
     DistEGNN gradient path.
 """
 import json
+import re
 import subprocess
 import sys
 import textwrap
@@ -225,6 +226,24 @@ def test_bf16_grads_finite_and_close():
     jax.tree.map(rel_l2, gb, gf)
 
 
+@pytest.mark.parametrize("precision,event,other", [
+    ("f32", "edge_onehot_split3", "edge_onehot_bf16"),
+    ("bf16", "edge_onehot_bf16", "edge_onehot_split3")])
+def test_onehot_counter_follows_precision(precision, event, other):
+    """Each fused edge dispatch records the pieces of its one-hot
+    products: three bf16 pieces under f32 compute, one under bf16."""
+    g = _graph(seed=19)
+    cfg, params, apply_full = resolve_model(
+        "fast_egnn", jax.random.PRNGKey(20), use_kernel=True, **_CFG,
+        n_virtual=2, s_dim=8)
+    cfg = cfg._replace(precision=precision)
+    mp.reset_dispatch_counts()
+    jax.eval_shape(lambda p: apply_full(p, cfg, g), params)
+    c = mp.dispatch_counts()
+    assert c.get(event, 0) == c.get("edge_kernel", 0) > 0, c
+    assert c.get(other, 0) == 0, c
+
+
 @pytest.mark.parametrize("precision", ["f32", "bf16"])
 def test_kernel_equivariance_rotation_translation(precision):
     """E(3) equivariance of the kernelised FastEGNN forward: rotating +
@@ -252,12 +271,22 @@ def test_kernel_equivariance_rotation_translation(precision):
                                np.asarray(x2) / scale, **tol)
 
 
+def _dot_dtypes(line: str) -> list[str]:
+    """Element types of a StableHLO dot's two operands and its result."""
+    sig = line.split(" : ", 1)[1]
+    return [t.split("x")[-1] for t in re.findall(r"tensor<([^>]*)>", sig)]
+
+
 @pytest.mark.parametrize("use_kernel", [False, True])
 def test_f32_dots_pin_highest_precision(use_kernel):
-    """The f32 configuration contracts in f32 on every backend: each dot of
-    the FastEGNN forward and backward, the model's own and (interpreted)
-    the kernels', carries ``precision=HIGHEST`` — a TPU runs a default
-    precision f32 dot as one bf16 pass."""
+    """The f32 configuration rounds no operand below f32 on any backend:
+    each dot of the FastEGNN forward and backward with an f32 operand, the
+    model's own and (interpreted) the kernels', carries
+    ``precision=HIGHEST`` — a TPU runs a default-precision f32 dot as one
+    bf16 pass.  Every other dot multiplies bf16 operands into an f32
+    result: the edge kernel's one-hot gathers and scatters, exact in one
+    pass (a one-hot and each piece of a three-piece split are bf16, and
+    their products are exact in f32)."""
     g = _graph(seed=17)
     cfg, params, apply_full = resolve_model(
         "fast_egnn", jax.random.PRNGKey(18), use_kernel=use_kernel, **_CFG,
@@ -266,16 +295,21 @@ def test_f32_dots_pin_highest_precision(use_kernel):
         params).as_text()
     dots = [ln for ln in text.splitlines() if "dot_general" in ln]
     assert dots
-    assert all("precision = [HIGHEST, HIGHEST]" in ln for ln in dots), [
-        ln for ln in dots if "HIGHEST" not in ln][:3]
+    f32 = [ln for ln in dots if "f32" in _dot_dtypes(ln)[:2]]
+    assert all("precision = [HIGHEST, HIGHEST]" in ln for ln in f32), [
+        ln for ln in f32 if "HIGHEST" not in ln][:3]
+    onehot = [ln for ln in dots if ln not in f32]
+    assert all(_dot_dtypes(ln) == ["bf16", "bf16", "f32"] for ln in onehot), [
+        ln for ln in onehot if _dot_dtypes(ln) != ["bf16", "bf16", "f32"]][:3]
+    assert bool(onehot) == use_kernel
 
 
 # ------------------------------------------------- train-step acceptance
 def test_train_step_dispatch_acceptance():
     """The PR's acceptance telemetry: a single-device FastEGNN training
     step with ``use_kernel=True`` over layout-carrying batches reports
-    ``virtual_kernel > 0``, ``virtual_jnp == 0`` and zero trace-time edge
-    regroups."""
+    ``virtual_kernel > 0``, ``virtual_jnp == 0``, zero trace-time edge
+    regroups, and f32 one-hot products by three exact bf16 pieces."""
     from repro.data.nbody import generate_nbody_dataset
     from repro.pipeline import build_pipeline
     from repro.training.trainer import TrainConfig
@@ -293,6 +327,8 @@ def test_train_step_dispatch_acceptance():
     assert c.get("virtual_kernel", 0) > 0, c
     assert c.get("virtual_jnp", 0) == 0, c
     assert c.get("edge_kernel", 0) > 0, c
+    assert c.get("edge_onehot_split3", 0) > 0, c
+    assert c.get("edge_onehot_bf16", 0) == 0, c
     assert c.get("edge_layout_regroup", 0) == 0, c
     assert c.get("edge_layout_host", 0) > 0, c
 

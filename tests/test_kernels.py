@@ -321,6 +321,127 @@ def test_edge_pathway_kernel_explicit_small_windows():
                                        err_msg=f"tiling {window}x{swindow}")
 
 
+# ------------------------------------------- exact one-hot products (f32)
+F32_MAX = np.finfo(np.float32).max
+#: below 2**-103 the low piece can fall under f32's least normal, and
+#: arithmetic that flushes subnormals (XLA's, here and on the TPU) drops it
+EXACT_FLOOR = np.float32(2.0 ** -103)
+#: subnormal f32: flushed to zero by that same arithmetic
+SUBNORMALS = np.float32([1e-40, -3e-42, 1.4e-45, 1.1754942e-38])
+
+
+def _wide_f32(rng, shape):
+    """Signed f32 values log-uniform over 1e-30..1e30, with zeros of both
+    signs, subnormals, the exactness floor, and the largest finite f32 and
+    the values about bf16's overflow edge, which a rounding split would
+    send to inf."""
+    v = (10.0 ** rng.uniform(-30, 30, shape)).astype(np.float32)
+    v *= rng.choice(np.float32([-1, 1]), shape)
+    edge = np.float32((2.0 - 2.0 ** -8) * 2.0 ** 127)
+    special = np.concatenate([
+        np.float32([0.0, -0.0, F32_MAX, -F32_MAX, edge,
+                    np.nextafter(edge, np.float32(0)), EXACT_FLOOR,
+                    -EXACT_FLOOR]), SUBNORMALS])
+    flat = v.reshape(-1)
+    flat[:special.size] = special
+    return v
+
+
+def _assert_bitwise(got, want):
+    """Bit for bit, where a zero may come back as either zero and a
+    subnormal (which the arithmetic reads as zero) as a zero."""
+    got, want = np.asarray(got), np.asarray(want)
+    normal = np.abs(want) >= np.finfo(np.float32).tiny
+    np.testing.assert_array_equal(got[normal].view(np.uint32),
+                                  want[normal].view(np.uint32))
+    assert np.all(got[~normal] == 0)
+
+
+@pytest.mark.parametrize("jit", [False, True])
+def test_split_pieces_round_trip_bitwise(jit):
+    """hi + mid + lo gives back every finite f32 of magnitude 2**-103 or
+    more bit for bit (a zero as +0, a subnormal as 0), compiled too."""
+    from repro.kernels.edge_message import split_pieces
+
+    v = _wide_f32(np.random.default_rng(0), (4096,))
+    split = jax.jit(split_pieces, static_argnums=1) if jit else split_pieces
+    pieces = split(jnp.asarray(v), 3)
+    assert all(p.dtype == jnp.bfloat16 for p in pieces)
+    hi, mid, lo = (p.astype(jnp.float32) for p in pieces)
+    _assert_bitwise((hi + mid) + lo, v)
+
+
+@pytest.mark.parametrize("compute", ["float32", "bfloat16"])
+def test_packed_onehot_gather_equals_indexing(compute):
+    """A bf16 one-hot against the packed pieces, accumulated in f32,
+    returns the gathered rows bitwise: the f32 values under f32 compute
+    (three pieces), their bf16 rounding under bf16 compute (one)."""
+    from repro.kernels.edge_message import _gather, onehot_pieces, pack
+
+    rng = np.random.default_rng(1)
+    h = _wide_f32(rng, (512, 64))
+    x = _wide_f32(rng, (512, 3))
+    if compute == "bfloat16":  # in range of the bf16 operands
+        h, x = (np.where(np.abs(a) < 1e38, a, np.float32(1)) for a in (h, x))
+    ids = rng.integers(0, 512, (128, 1)).astype(np.int32)
+    cdt = jnp.dtype(compute)
+    pieces = onehot_pieces(cdt)
+    assert pieces == (3 if compute == "float32" else 1)
+    packed = pack([jnp.asarray(h).astype(cdt), jnp.asarray(x).astype(cdt)],
+                  pieces)
+    assert packed.dtype == jnp.bfloat16 and packed.shape[1] % 128 == 0
+    got = _gather(jnp.asarray(ids), packed, 67, pieces, jnp.float32)
+    want = np.concatenate([h, x], axis=1)[ids[:, 0]]
+    _assert_bitwise(got, jnp.asarray(want).astype(cdt).astype(jnp.float32))
+
+
+@pytest.mark.parametrize("gate_mode", ["mlp", "identity", "none"])
+def test_edge_kernel_multiwindow_fwd_and_vjp_match_ref(gate_mode):
+    """Several receiver and several sender windows (4 x 2 bands): the
+    packed-piece forward and both fused backward passes match the
+    oracle and its vjp to f32 round-off."""
+    from repro.kernels.edge_message import (edge_pathway_bwd_fused,
+                                            pick_windows)
+
+    n, e, dh, hid = 900, 2400, 8, 16
+    spec = {"mlp": _EDGE_SPECS["egnn"], "identity": _EDGE_SPECS["schnet"],
+            "none": _EDGE_SPECS["mpnn"]}[gate_mode]
+    x, h, g = _skewed_graph(n, e, dh, seed=21)
+    lp = _edge_params(jax.random.PRNGKey(22), dh, hid, spec)
+    hk, ws = kops.unpack_edge_params(lp, h, spec)
+    tiling = dict(window=256, swindow=512)
+    window, swindow, n_pad = pick_windows(n, **tiling)
+    assert n_pad // window == 4 and n_pad // swindow == 2
+    kw = dict(gate_mode=spec.gate, rel_mode=spec.rel, clamp=spec.coord_clamp)
+    args = (g.senders, g.receivers, g.edge_mask)
+
+    def oracle(x, hk, *ws):
+        return ref.edge_pathway_ref(x, hk, *args, *ws, **kw)
+
+    want, vjp = jax.vjp(oracle, x, hk, *ws)
+    got = edge_pathway_fused(x, hk, *args, *ws, interpret=True, **tiling,
+                             **kw)
+    ks = jax.random.split(jax.random.PRNGKey(23), 2)
+    g_dx = jax.random.normal(ks[0], want[0].shape)
+    g_mh = jax.random.normal(ks[1], want[1].shape)
+    want_g = vjp((g_dx, g_mh, jnp.zeros_like(want[2])))
+    got_g = edge_pathway_bwd_fused(x, hk, *args, *ws, got[2], g_dx, g_mh,
+                                   interpret=True, **tiling, **kw)
+
+    def close(a, b):
+        if b.size == 0:
+            return
+        scale = float(jnp.max(jnp.abs(b))) + 1e-30
+        np.testing.assert_allclose(np.asarray(a) / scale,
+                                   np.asarray(b) / scale, rtol=0, atol=1e-5)
+
+    for a, b in zip(got, want):
+        close(a, b)
+    assert len(got_g) == len(want_g)
+    for a, b in zip(got_g, want_g):
+        close(a, b)
+
+
 @pytest.mark.parametrize("n,c,sigma,block", [(100, 3, 1.5, 64), (1024, 10, 3.0, 256),
                                              (33, 1, 0.7, 1024)])
 def test_mmd_kernel(n, c, sigma, block):

@@ -113,8 +113,8 @@ def test_edge_fwd_bwd_compiles(chip, n, precision):
     _compile(step, _shapes(lp, chip), graph, lay)
 
 
-@pytest.mark.parametrize("hid,precision", [(256, "f32"), (384, "f32"),
-                                           (256, "bf16"), (512, "bf16")])
+@pytest.mark.parametrize("hid,precision", [(256, "f32"), (320, "f32"),
+                                           (512, "bf16"), (576, "bf16")])
 def test_edge_eligibility_agrees_with_compiler(chip, hid, precision):
     """Either side of the VMEM budget: what kernel_supported admits
     compiles, and what it refuses does not."""
