@@ -24,7 +24,9 @@ upper readings); and the faults asked for, each on those seeds:
   to each shard;
 * ``program_bf16``: the program's own bfloat16 kernel path.
 
-Each reading is one JSON line: kind, seed and the three numbers.
+Each reading is one JSON line: kind, seed and the three numbers, and on a
+sharded cell ``virtual_gap`` beside them (a fault of the reference alone
+leaves the forward, and so the virtual nodes, as the reference's own).
 """
 from __future__ import annotations
 
@@ -40,16 +42,22 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path[:0] = [ROOT, os.path.join(ROOT, "src")]
 
 
-def as_checked(ref_out: dict) -> dict:
-    """A reference run dressed as the program's first steps."""
+def as_checked(ref_out: dict, shards: int = 1) -> dict:
+    """A reference run dressed as the program's first steps: on a sharded
+    cell its virtual nodes as each of ``shards`` shards would hold them."""
     import jax
+    import numpy as np
 
     from bench.check import BETA1
 
-    return dict(losses=ref_out["losses"],
-                m1=jax.tree.map(lambda g: g * (1 - BETA1),
-                                ref_out["grad1"]),
-                params=ref_out["params"])
+    out = dict(losses=ref_out["losses"],
+               m1=jax.tree.map(lambda g: g * (1 - BETA1), ref_out["grad1"]),
+               params=ref_out["params"])
+    if "virtual" in ref_out:
+        vs = ref_out["virtual"]
+        out["virtual"] = {k: np.broadcast_to(vs[k], (shards,) + vs[k].shape)
+                          for k in ("z", "s")}
+    return out
 
 
 @contextlib.contextmanager
@@ -116,6 +124,7 @@ def main(argv=None) -> int:
         raise SystemExit("half_batch needs a batch of two scenes or more; "
                          "half of a sharded scene is half_shards")
     driver.enable_compile_cache()
+    shards = cfg["devices"]
 
     def emit(kind, seed, values, **extra):  # writes to ``out``, below
         row = dict(workload=args.workload, kind=kind, seed=seed, **values,
@@ -128,11 +137,10 @@ def main(argv=None) -> int:
 
     def program(seed, cfg_run, patch=None):
         with patch() if patch else contextlib.nullcontext():
-            w = win.Warm(cfg_run, traffic, seed, win.CHECKED_STEPS)
-            res = (w.pool, w.keys, w.params0, w.checked)
-            del w
-        gc.collect()
-        return res
+            kept = win.Warm(cfg_run, traffic, seed, win.CHECKED_STEPS).keep()
+            gc.collect()
+            checked = win.program_checked(kept)
+        return kept.pool, kept.keys, kept.params0, checked
 
     with (open(args.out, "a") if args.out
           else contextlib.nullcontext()) as out:
@@ -152,26 +160,26 @@ def main(argv=None) -> int:
             ref_mod = registry.reference(cfg["reference"])
             _, batches, rkeys = win.reference_batches(
                 cfg, traffic, pool, keys, win.CHECKED_STEPS)
-            high = ref_mod.train(params0, batches, rkeys, cfg, mode="high")
-            emit("control_high", seed,
-                 check.gaps(as_checked(high), ref_hi, params0),
-                 step_loss_gaps=check.step_loss_gaps(as_checked(high), ref_hi))
+            high = as_checked(ref_mod.train(params0, batches, rkeys, cfg,
+                                            mode="high"), shards)
+            emit("control_high", seed, check.gaps(high, ref_hi, params0),
+                 step_loss_gaps=check.step_loss_gaps(high, ref_hi))
             for f in faults:
                 if f == "half_batch":  # the reference on half of each batch
                     _, hb = win.reference_gaps(
                         cfg, traffic, pool, keys, params0, checked,
                         scenes_per_step=traffic["batch"] // 2)
-                    fc = as_checked(hb)
+                    fc = as_checked(hb, shards)
                 elif f == "half_shards":
                     _, hs = win.reference_gaps(
                         cfg, traffic, pool, keys, params0, checked,
                         loss_shards=cfg["devices"] // 2)
-                    fc = as_checked(hs)
+                    fc = as_checked(hs, shards)
                 elif f == "own_shard":
                     _, os_ = win.reference_gaps(
                         cfg, traffic, pool, keys, params0, checked,
                         own_shard=True)
-                    fc = as_checked(os_)
+                    fc = as_checked(os_, shards)
                 elif f == "exchange":
                     _, _, _, fc = program(seed, cfg, exchange_left_out)
                 elif f == "loss_local":

@@ -1,5 +1,6 @@
 """What decides ``correct``: the program's first training steps against the
-plain reference's, by three numbers, each held to a limit of its own.
+plain reference's, by three numbers (four on a sharded cell), each held to
+a limit of its own.
 
 * ``loss_gap``: over the first ``LOSS_STEPS`` steps, the largest
   ``|loss - ref| / |ref|``.  The third step's loss is left out: after two
@@ -13,6 +14,14 @@ plain reference's, by three numbers, each held to a limit of its own.
   and the median leaf's.
 * ``update_gap``: the parameters' change over the first steps, by the worst
   leaf, measured the same way.
+* ``virtual_gap`` (sharded cells alone): the virtual nodes after the full
+  forward of the first step's scene at the initial weights, every shard's
+  copy against the reference's: the larger of the worst coordinate gap
+  over the RMS distance of the scene's particles from their centre of mass
+  and the worst feature gap over the RMS of the reference's features.  It
+  sees the exchange between chips left out, which a balanced random
+  partition hides from the loss (each shard's own centre of mass and
+  virtual-node means lie near the global ones).
 
 Leaves whose reference gradient is below a thousandth of the median leaf's
 move by round-off alone and are left out of both leaf numbers (a rule on
@@ -58,10 +67,22 @@ def step_loss_gaps(prog: dict, ref: dict) -> list:
             for g in np.abs(lp - lr) / np.abs(lr)]
 
 
+def virtual_gap(prog: dict, ref: dict) -> float:
+    """``prog``: z (D, C, 3) and s (D, C, S), each shard's copy of the
+    virtual nodes; ``ref``: z (C, 3), s (C, S) and the scene's spread."""
+    z_ref, s_ref = (np.asarray(ref[k], np.float64) for k in ("z", "s"))
+    z = np.max(np.abs(np.asarray(prog["z"], np.float64) - z_ref))
+    s = np.max(np.abs(np.asarray(prog["s"], np.float64) - s_ref))
+    z, s = z / ref["spread"], s / np.sqrt(np.mean(s_ref ** 2))
+    return float(max(z, s)) if np.isfinite([z, s]).all() else float("inf")
+
+
 def gaps(prog: dict, ref: dict, params0) -> dict:
-    """The three numbers.  ``prog``: losses (first steps), m1 (the
-    optimizer's first moment after step 1), params (after the last of the
-    first steps).  ``ref``: what ``reference.train`` returns."""
+    """The three numbers, and ``virtual_gap`` where the reference ran
+    sharded.  ``prog``: losses (first steps), m1 (the optimizer's first
+    moment after step 1), params (after the last of the first steps) and,
+    sharded, virtual (see :func:`virtual_gap`).  ``ref``: what
+    ``reference.train`` returns."""
     loss_gap = max(step_loss_gaps(prog, ref)[:LOSS_STEPS])
     g_ref = _leaf_norms(ref["grad1"])
     keep = g_ref >= QUIET_LEAF * np.median(g_ref)
@@ -74,6 +95,8 @@ def gaps(prog: dict, ref: dict, params0) -> dict:
     out = dict(loss_gap=loss_gap,
                grad_gap=_worst_leaf_gap(g_prog, ref["grad1"], keep),
                update_gap=_worst_leaf_gap(d_prog, d_ref, keep))
+    if "virtual" in ref:
+        out["virtual_gap"] = virtual_gap(prog["virtual"], ref["virtual"])
     return {k: (v if np.isfinite(v) else float("inf")) for k, v in out.items()}
 
 

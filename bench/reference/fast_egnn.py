@@ -53,13 +53,23 @@ def _dot(a, b, mode: str):
         return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
     bf = jnp.bfloat16
     mm = partial(jnp.matmul, preferred_element_type=jnp.float32)
-    a_hi, b_hi = a.astype(bf), b.astype(bf)
-    out = mm(a_hi, b_hi)
-    if mode == "high":
-        a_lo = (a - a_hi.astype(jnp.float32)).astype(bf)
-        b_lo = (b - b_hi.astype(jnp.float32)).astype(bf)
-        out = out + mm(a_hi, b_lo) + mm(a_lo, b_hi)
-    return out
+    if mode == "bf16":
+        return mm(a.astype(bf), b.astype(bf))
+    a_hi, b_hi = _bf16_hi(a), _bf16_hi(b)
+    a_lo, b_lo = (a - a_hi).astype(bf), (b - b_hi).astype(bf)
+    a_hi, b_hi = a_hi.astype(bf), b_hi.astype(bf)
+    return mm(a_hi, b_hi) + mm(a_hi, b_lo) + mm(a_lo, b_hi)
+
+
+def _bf16_hi(a):
+    """``a`` (f32) rounded to the nearest bfloat16 (ties to even), held in
+    f32.  Cut from the bits, because a rounding round trip ``f32 -> bf16
+    -> f32`` is one that XLA may fold away where it allows excess
+    precision, and the low piece ``a - hi`` would then be 0."""
+    bits = jax.lax.bitcast_convert_type(a, jnp.uint32)
+    bits = bits + jnp.uint32(0x7FFF) + ((bits >> 16) & jnp.uint32(1))
+    return jax.lax.bitcast_convert_type(bits & jnp.uint32(0xFFFF0000),
+                                        jnp.float32)
 
 
 def _mlp(layers, x, mode):
@@ -77,8 +87,8 @@ def _channel(layers, c):
 
 
 def forward(params, g: dict, cfg: dict, mode: str):
-    """(x, h, z) after the L layers.  ``g``: x, v, h (N, ·) real nodes only;
-    snd, rcv, em (E,) edges (padding slots carry em = 0)."""
+    """(x, h, z, s) after the L layers.  ``g``: x, v, h (N, ·) real nodes
+    only; snd, rcv, em (E,) edges (padding slots carry em = 0)."""
     x, v, snd, rcv, em = g["x"], g["v"], g["snd"], g["rcv"], g["em"]
     n = x.shape[0]
     c_n = cfg["n_virtual"]
@@ -128,7 +138,7 @@ def forward(params, g: dict, cfg: dict, mode: str):
         s = s + jnp.stack([_mlp(_channel(vb["phi_s"], c), s_in[c], mode)
                            for c in range(c_n)])
         x = x + dx
-    return x, h, z
+    return x, h, z, s
 
 
 def _rbf(a, b, sigma):
@@ -138,8 +148,8 @@ def _rbf(a, b, sigma):
 
 def scene_loss(params, g: dict, key, cfg: dict, mode: str):
     """Eq. 11 for one scene on one device (MMD on ``mmd_sample`` targets
-    drawn under ``key``)."""
-    x, _, z = forward(params, g, cfg, mode)
+    drawn under ``key``), with the virtual nodes (z, s) after the forward."""
+    x, _, z, s = forward(params, g, cfg, mode)
     t = g["x1"]
     mse = jnp.sum((x - t) ** 2) / x.shape[0] / 3.0
     c_n, k = z.shape[0], cfg["mmd_sample"]
@@ -148,7 +158,7 @@ def scene_loss(params, g: dict, key, cfg: dict, mode: str):
     sig = cfg["mmd_sigma"]
     mmd = (jnp.sum(_rbf(z, z, sig)) / (c_n * c_n)
            - jnp.sum(_rbf(t[idx], z, sig)) / (k * c_n))
-    return mse + cfg["lam_mmd"] * mmd
+    return mse + cfg["lam_mmd"] * mmd, (z, s)
 
 
 def union_loss(params, g: dict, cfg: dict, mode: str):
@@ -159,8 +169,9 @@ def union_loss(params, g: dict, cfg: dict, mode: str):
     Two faults that calibration reads, named in ``cfg``: ``loss_shards``
     keeps the terms of the first shards alone, the mean taken over them;
     ``own_shard`` keeps the first shard's share of the global sums, the
-    gradient one chip has before the all-reduce."""
-    x, _, z = forward(params, g, cfg, mode)
+    gradient one chip has before the all-reduce.  Returns the loss with
+    the virtual nodes (z, s) after the forward."""
+    x, _, z, s = forward(params, g, cfg, mode)
     t = g["x1"]
     d = cfg["devices"]
     err = jnp.sum((x - t) ** 2, axis=-1)
@@ -173,19 +184,27 @@ def union_loss(params, g: dict, cfg: dict, mode: str):
     if cfg.get("own_shard"):
         mine = (g["shard"] == 0).astype(t.dtype)
         return (jnp.sum(err * mine) / x.shape[0] / 3.0
-                + cfg["lam_mmd"] * mmd[0] / d)
+                + cfg["lam_mmd"] * mmd[0] / d), (z, s)
     keep = cfg.get("loss_shards", d)
     w = (g["shard"] < keep).astype(t.dtype)
     mse = jnp.sum(err * w) / jnp.sum(w) / 3.0
-    return mse + cfg["lam_mmd"] * jnp.mean(mmd[:keep])
+    return mse + cfg["lam_mmd"] * jnp.mean(mmd[:keep]), (z, s)
 
 
 @partial(jax.jit, static_argnames=("cfg_items", "mode", "dist"))
 def _value_and_grad(params, g, key, *, cfg_items, mode, dist):
     cfg = dict(cfg_items)
     if dist:
-        return jax.value_and_grad(union_loss)(params, g, cfg, mode)
-    return jax.value_and_grad(scene_loss)(params, g, key, cfg, mode)
+        return jax.value_and_grad(union_loss, has_aux=True)(params, g, cfg,
+                                                            mode)
+    return jax.value_and_grad(scene_loss, has_aux=True)(params, g, key, cfg,
+                                                        mode)
+
+
+def spread(x) -> float:
+    """The RMS distance of a scene's particles from their centre of mass."""
+    x = np.asarray(x, np.float64)
+    return float(np.sqrt(np.mean(np.sum((x - x.mean(0)) ** 2, axis=-1))))
 
 
 def _global_norm(tree):
@@ -217,7 +236,10 @@ def train(params0, batches, keys, cfg: dict, mode: str = "highest") -> dict:
     alone and averaged, so the memory held is one scene's.
 
     Returns ``losses`` (per step), ``grad1`` (the clipped first gradient)
-    and ``params`` (after the last step).
+    and ``params`` (after the last step); on DistEGNN also ``virtual``,
+    the virtual nodes after the forward of the first step's first scene at
+    ``params0``: coordinates ``z`` (C, 3), features ``s`` (C, S) and the
+    scene's ``spread`` (see :func:`spread`).
     """
     dist = cfg.get("devices", 1) > 1
     cfg_items = tuple(sorted((k, v) for k, v in cfg.items()
@@ -226,13 +248,17 @@ def train(params0, batches, keys, cfg: dict, mode: str = "highest") -> dict:
     m = jax.tree.map(jnp.zeros_like, params0)
     v = jax.tree.map(jnp.zeros_like, params0)
     losses, grad1 = [], None
+    out = {}
     with jax.default_matmul_precision("highest"):
         for step, (scenes, key) in enumerate(zip(batches, keys), start=1):
             skeys = jax.random.split(key, len(scenes))
             tot_loss, tot_grad = 0.0, None
             for g, k in zip(scenes, skeys):
-                loss, grad = _value_and_grad(params, g, k, cfg_items=cfg_items,
-                                             mode=mode, dist=dist)
+                (loss, (z, s)), grad = _value_and_grad(
+                    params, g, k, cfg_items=cfg_items, mode=mode, dist=dist)
+                if dist and "virtual" not in out:
+                    out["virtual"] = dict(z=np.asarray(z), s=np.asarray(s),
+                                          spread=spread(g["x"]))
                 tot_loss = tot_loss + loss
                 tot_grad = grad if tot_grad is None else jax.tree.map(
                     jnp.add, tot_grad, grad)
@@ -242,7 +268,7 @@ def train(params0, batches, keys, cfg: dict, mode: str = "highest") -> dict:
             losses.append(float(tot_loss / b))
             if grad1 is None:
                 grad1 = clipped
-    return dict(losses=losses, grad1=grad1, params=params)
+    return dict(out, losses=losses, grad1=grad1, params=params)
 
 
 def scene_graph(scene, r: float, edge_cap: int, assign=None) -> dict:

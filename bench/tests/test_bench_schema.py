@@ -61,15 +61,19 @@ def test_names_units_and_entry_keys(bm):
 def test_every_cell_resolves(bm):
     """Each cell, and each cell held back, finds its configuration, traffic
     mix, limits and every per-layer metric's reader by name, and reports
-    setup_s, another end-to-end metric and a per-layer metric."""
+    setup_s, another end-to-end metric and a per-layer metric.  Every cell
+    is held to the three training numbers, and a sharded one to
+    virtual_gap besides."""
     held = registry.held_back()
     assert not {w["name"] for w in held["workloads"]} & {
         w["name"] for w in bm["workloads"]}
     for w in bm["workloads"] + held["workloads"]:
         c = registry.cell(w["name"])
         assert c["config"]["devices"] == w["chips"]
-        assert set(c["limits"]["limits"]) == {"loss_gap", "grad_gap",
-                                              "update_gap"}
+        want = {"loss_gap", "grad_gap", "update_gap"}
+        if c["config"]["devices"] > 1:
+            want.add("virtual_gap")
+        assert set(c["limits"]["limits"]) == want
         assert len(c["end_to_end"]) >= 2 and c["per_layer"]
         for m in c["per_layer"]:
             assert callable(registry.metric_reader(m["name"]))

@@ -13,6 +13,12 @@ window then re-iterates the same stream, epoch after epoch, as
 ``Pipeline.fit`` would, with at most ``IN_FLIGHT`` steps queued on the
 device, and ends with a block on the last step.
 
+On a sharded cell the check also runs the program's sharded forward
+(``repro.distributed.dist_egnn.build_dist_apply``) once, after the window
+and after ``release``: at the initial weights, on the first step's batch
+(kept from set-up on the host), for the virtual nodes that each shard
+holds after the last layer.
+
 Around the loop's three host calls the window records its own spans
 (``jax.profiler.TraceAnnotation``): ``stream_next`` (waiting on the
 stream), ``train_step_dispatch`` (enqueueing the step) and ``block``
@@ -97,10 +103,14 @@ def build(cfg: dict, traffic: dict, seed: int, mark=lambda name: None):
 
 class Loop:
     """The training loop the window times: re-iterates the stream epoch
-    after epoch and keeps at most ``IN_FLIGHT`` steps queued."""
+    after epoch and keeps at most ``IN_FLIGHT`` steps queued.  On a sharded
+    cell it keeps its first batch (``first``), which the check reads
+    again."""
 
     def __init__(self, pipe, stream, keys: np.ndarray):
         self.step_fn = pipe.train_step
+        self.keep_first = pipe.mesh is not None
+        self.first = None
         self.stream = stream
         self.keys = keys
         self.it = iter(stream)
@@ -117,6 +127,8 @@ class Loop:
                 self.it = iter(self.stream)
                 self._pos = 0
                 b = next(self.it)
+        if self.keep_first and self.first is None:
+            self.first = b
         self.batch_index.append(self._pos)
         self._pos += 1
         return b
@@ -163,6 +175,18 @@ def reference_batches(cfg: dict, traffic: dict, pool: list, keys, steps: int,
     return ref, out, [jnp.asarray(keys[k]) for k in range(steps)]
 
 
+class Kept(NamedTuple):
+    """What the check needs once the program's state is freed: the scene
+    pool, step keys, initial weights, what the first steps produced and,
+    on a sharded cell, ``first``: the first step's batch on the host, with
+    the program's model configuration and mesh (None on one chip)."""
+    pool: list
+    keys: np.ndarray
+    params0: dict
+    checked: dict
+    first: tuple | None
+
+
 class Warm:
     """A cell after set-up: the program's pipeline, stream and loop, the
     state the window starts from, and what the first steps produced."""
@@ -189,14 +213,45 @@ class Warm:
         losses = [float(l) for l in self.loop.losses[:CHECKED_STEPS]]
         self.checked = dict(losses=losses, m1=m1, params=params_k)
 
+    def keep(self) -> Kept:
+        first = None
+        if self.loop.first is not None:
+            first = (_host(self.loop.first), self.pipe.cfg, self.pipe.mesh)
+        return Kept(self.pool, self.keys, self.params0, self.checked, first)
+
+
+def program_virtual(first: tuple, params0) -> dict:
+    """The virtual nodes after the program's sharded forward at ``params0``
+    on the first scene of ``first``'s batch: z (D, C, 3) and s (D, C, S),
+    each shard's copy (see ``check.virtual_gap``)."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from repro.distributed.dist_egnn import build_dist_apply
+    from repro.distributed.sharding import sharded_batch_from_process_local
+
+    batch, model_cfg, mesh = first
+    params = jax.device_put(params0, NamedSharding(mesh, PartitionSpec()))
+    sb = sharded_batch_from_process_local(mesh, batch._asdict())
+    _, vs = build_dist_apply(model_cfg, mesh)(params, sb)
+    return dict(z=np.asarray(vs.z)[:, 0], s=np.asarray(vs.s)[:, 0])
+
+
+def program_checked(kept: Kept) -> dict:
+    """What the check compares: the first steps' readings, with the
+    program's virtual nodes added on a sharded cell."""
+    if kept.first is None:
+        return kept.checked
+    return dict(kept.checked, virtual=program_virtual(kept.first,
+                                                      kept.params0))
+
 
 def reference_gaps(cfg: dict, traffic: dict, pool: list, keys, params0,
                    checked: dict, scenes_per_step: int | None = None,
                    **ref_cfg):
-    """The reference's first steps from ``params0`` and the three numbers
-    comparing ``checked`` with them; returns (numbers, reference output).
-    ``ref_cfg`` overrides configuration keys of the reference alone (a
-    fault that calibration reads)."""
+    """The reference's first steps from ``params0`` and the numbers
+    comparing ``checked`` with them (``check.gaps``); returns (numbers,
+    reference output).  ``ref_cfg`` overrides configuration keys of the
+    reference alone (a fault that calibration reads)."""
     ref, batches, rkeys = reference_batches(cfg, traffic, pool, keys,
                                             CHECKED_STEPS, scenes_per_step)
     out = ref.train(params0, batches, rkeys, dict(cfg, **ref_cfg),
@@ -253,13 +308,13 @@ class Session:
 
     def release(self) -> None:
         """Free the program's state; keep what the check needs."""
-        w = self.warm
-        self.kept = (w.pool, w.keys, w.params0, w.checked)
-        del self.warm, w
+        self.kept = self.warm.keep()
+        del self.warm
 
     def check(self) -> tuple[dict, str]:
         """The numbers that decide ``correct``, and a line for the log."""
-        pool, keys, params0, checked = self.kept
+        pool, keys, params0, _, _ = self.kept
+        checked = program_checked(self.kept)
         values, ref_out = reference_gaps(self.cfg, self.traffic, pool, keys,
                                          params0, checked)
         return values, (f"program losses {checked['losses']}, reference "
