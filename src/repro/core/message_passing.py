@@ -143,11 +143,12 @@ def _scaled_rel(rel: Array, d2: Array, spec: EdgeSpec) -> Array:
 # Events: 'edge_kernel' / 'edge_jnp' (this module) and, with each
 # 'edge_kernel', the pieces of its one-hot products: 'edge_onehot_split3'
 # (f32 values as three exact bf16 pieces) or 'edge_onehot_bf16' (bf16
-# compute, one piece); 'virtual_kernel' / 'virtual_jnp'
-# (core.virtual_nodes), 'edge_layout_host' / 'edge_layout_regroup'
-# (kernels.edge_message).  Because jit caches traces,
-# counts reflect *traces*, not executions: reset before building a fresh
-# jitted program to observe its dispatch decisions.
+# compute, one piece); 'tfn_edge_kernel' / 'tfn_edge_jnp' (models.tfn's
+# edge pathway); 'virtual_kernel' / 'virtual_jnp' (core.virtual_nodes),
+# 'edge_layout_host' / 'edge_layout_regroup' (kernels.edge_message).
+# Because jit caches traces, counts reflect *traces*, not executions:
+# reset before building a fresh jitted program to observe its dispatch
+# decisions.
 DISPATCH_COUNTS: dict[str, int] = {}
 
 
@@ -174,7 +175,8 @@ def dispatch_mode(counts: dict, use_kernel: bool, backend_mode: str) -> str:
     """
     if not use_kernel:
         return "jnp"
-    if counts.get("edge_kernel", 0) and not counts.get("edge_layout_regroup", 0):
+    fused = counts.get("edge_kernel", 0) or counts.get("tfn_edge_kernel", 0)
+    if fused and not counts.get("edge_layout_regroup", 0):
         return backend_mode
     return "fallback"
 

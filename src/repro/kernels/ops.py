@@ -33,6 +33,7 @@ from repro.kernels.edge_message import (edge_pathway_bwd_fused,
                                         edge_pathway_fused)
 from repro.kernels.mmd_rbf import mmd_cross_grads, mmd_cross_sum
 from repro.kernels.runtime import resolve_precision
+from repro.kernels.tfn_edge import tfn_edge_bwd_fused, tfn_edge_fused
 from repro.kernels.virtual_message import (virtual_pathway_bwd_fused,
                                            virtual_pathway_fused)
 
@@ -88,18 +89,24 @@ def _edge_custom(gate_mode: str, rel_mode: str, clamp: float,
                                        g_dx, g_mh, layout=lay, **kw)
         gx, gh, *gws = (g.astype(p.dtype)
                         for g, p in zip(grads, (x, h, *ws)))
-        zint = lambda a: np.zeros(a.shape, dtype=float0)
         if with_layout:
-            glay = type(lay)(zint(lay.senders), zint(lay.receivers),
-                             jnp.zeros_like(lay.edge_mask),
-                             zint(lay.block_rwin), zint(lay.block_swin),
-                             meta=lay.meta)
-            return (gx, gh, zint(snd), zint(rcv), jnp.zeros_like(em),
-                    glay, *gws)
-        return (gx, gh, zint(snd), zint(rcv), jnp.zeros_like(em), *gws)
+            return (gx, gh, _zint(snd), _zint(rcv), jnp.zeros_like(em),
+                    _layout_cotangent(lay), *gws)
+        return (gx, gh, _zint(snd), _zint(rcv), jnp.zeros_like(em), *gws)
 
     f.defvjp(fwd, bwd)
     return f
+
+
+def _zint(a):
+    return np.zeros(a.shape, dtype=float0)
+
+
+def _layout_cotangent(lay):
+    """The zero cotangent of a threaded ``EdgeLayout`` (module docstring)."""
+    return type(lay)(_zint(lay.senders), _zint(lay.receivers),
+                     jnp.zeros_like(lay.edge_mask), _zint(lay.block_rwin),
+                     _zint(lay.block_swin), meta=lay.meta)
 
 
 def unpack_edge_params(lp, h: Array, spec) -> tuple[Array, tuple[Array, ...]]:
@@ -162,6 +169,61 @@ def edge_pathway(lp, h: Array, x: Array, g, spec,
                          prec)
         dx, mh, _deg = f(x, hk, g.senders, g.receivers, g.edge_mask, *ws)
     return dx, mh
+
+
+# ------------------------------------------------------------------- TFN MP
+@functools.lru_cache(maxsize=None)
+def _tfn_edge_custom(cutoff: float, clamp: float, precision=None):
+    """Per-variant custom_vjp wrapper of the fused TFN edge pathway
+    (``kernels/tfn_edge.py``).  Backward: its two fused passes; the only
+    residual is the forward's ``deg`` column.  The layout and the rbf
+    centres get zero cotangents."""
+    kw = dict(cutoff=cutoff, clamp=clamp, precision=resolve_precision(precision))
+
+    @jax.custom_vjp
+    def f(x, a, v, lay, centers, w1r, w2, b2):
+        return tfn_edge_fused(x, a, v, lay, centers, w1r, w2, b2, **kw)
+
+    def fwd(*args):
+        out = f(*args)
+        return out, (args, out[2])
+
+    def bwd(res, cots):
+        args, deg = res
+        x, a, v, lay, centers, w1r, w2, b2 = args
+        g_dx, g_h, _g_deg = cots  # deg is constant w.r.t. every input
+        grads = tfn_edge_bwd_fused(*args, deg, g_dx, g_h, **kw)
+        gx, ga, gv, gw1r, gw2, gb2 = (
+            g.astype(p.dtype) for g, p in zip(grads, (x, a, v, w1r, w2, b2)))
+        return (gx, ga, gv, _layout_cotangent(lay), jnp.zeros_like(centers),
+                gw1r, gw2, gb2)
+
+    f.defvjp(fwd, bwd)
+    return f
+
+
+def tfn_edge_pathway(radial, h: Array, x: Array, v: Array, layout, *,
+                     cutoff: float, clamp: float,
+                     precision=None) -> tuple[Array, Array]:
+    """Kernel-backed TFN edge pathway: ``(dx (N, 3), h_agg (N, 2))``.
+
+    The radial network's first-layer weight rows are ordered ``[rbf | h]``
+    (the concatenation order of ``models.tfn.tfn_edge_pathway``'s jnp
+    path); the ``h`` rows and the bias are applied per node here, in XLA
+    (under the scope ``radial_mlp``), and the kernel gathers the product.
+    Eligibility is the caller's (``models.tfn.edge_kernel_supported``).
+    """
+    w1, b1 = radial[0]["w"], radial[0]["b"]
+    n_rbf = w1.shape[0] - h.shape[-1]
+    with jax.named_scope("radial_mlp"):
+        a = jnp.matmul(h, w1[n_rbf:], precision=dot_precision(h, w1)) + b1
+    w2 = radial[1]["w"]
+    b2 = radial[1]["b"][None, :]
+    centers = jnp.linspace(0.0, cutoff, n_rbf, dtype=w1.dtype)[None, :]
+    f = _tfn_edge_custom(float(cutoff), float(clamp),
+                         resolve_precision(precision))
+    dx, h_agg, _deg = f(x, a, v, layout, centers, w1[:n_rbf], w2, b2)
+    return dx, h_agg
 
 
 # ---------------------------------------------------------------- virtual MP

@@ -107,6 +107,48 @@ def edge_pathway_ref(
     return dx, mh, deg[:, None]
 
 
+def tfn_edge_pathway_ref(
+    x: Array,  # (N, 3)
+    a: Array,  # (N, H)       per-node part of the radial layer 1: h W_h + b_1
+    v: Array,  # (N, 3)
+    snd: Array,  # (E,) int32
+    rcv: Array,  # (E,) int32
+    em: Array,  # (E,)        edge validity mask
+    centers: Array,  # (1, R) rbf centres on [0, cutoff]
+    w1r: Array,  # (R, H)     radial layer-1 weight rows for rbf(|r|)
+    w2: Array,  # (H, 6)      radial layer 2
+    b2: Array,  # (1, 6)
+    *,
+    cutoff: float,
+    clamp: float = float("inf"),
+):
+    """Fused TFN edge pathway (``kernels/tfn_edge.py``): on each edge j → i
+    the path weights ``w = clip(silu(a_j + rbf(|r|) W_r) W_2 + b_2, ±clamp)``
+    and the paths ``w0 v_j + w1 r̂ + w2 (r̂ × v_j) + w3 (r̂r̂ᵀ − I/3) v_j``
+    (type 1) and ``[w4, w5 r̂·v_j]`` (type 0), ``r = x_i − x_j``.
+
+    Returns (dx (N,3), h_agg (N,2), deg (N,1)): masked means onto the
+    receivers.
+    """
+    n = x.shape[0]
+    rel = x[rcv] - x[snd]
+    d = jnp.sqrt(jnp.sum(rel * rel, axis=-1, keepdims=True) + 1e-12)
+    rhat = rel / d
+    vj = v[snd]
+    rbf = jnp.exp(-(w1r.shape[0] / cutoff) * (d - centers) ** 2)
+    w = jnp.clip(jax.nn.silu(a[snd] + rbf @ w1r) @ w2 + b2, -clamp, clamp)
+    dot = jnp.sum(rhat * vj, axis=-1, keepdims=True)
+    em2 = em[:, None]
+    dx_e = (w[:, 0:1] * vj + w[:, 1:2] * rhat + w[:, 2:3] * jnp.cross(rhat, vj)
+            + w[:, 3:4] * (rhat * dot - vj / 3.0)) * em2
+    s0 = jnp.concatenate([w[:, 4:5], w[:, 5:6] * dot], axis=-1) * em2
+    deg = jax.ops.segment_sum(em, rcv, num_segments=n)
+    inv = (1.0 / jnp.maximum(deg, 1.0))[:, None]
+    dx = jax.ops.segment_sum(dx_e, rcv, num_segments=n) * inv
+    h_agg = jax.ops.segment_sum(s0, rcv, num_segments=n) * inv
+    return dx, h_agg, deg[:, None]
+
+
 def mmd_cross_ref(x: Array, z: Array, node_mask: Array, sigma: float) -> Array:
     """Σ_i mask_i Σ_c exp(−‖x_i−z_c‖²/2σ²) — the MMD cross term numerator."""
     d2 = jnp.sum((x[:, None, :] - z[None, :, :]) ** 2, axis=-1)

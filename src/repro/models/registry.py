@@ -62,8 +62,10 @@ def compose_virtual(base: ModelSpec, n_virtual: int = 3) -> ModelSpec:
 # Every apply_full shares one signature:
 #   apply_full(params, cfg, graph, axis_name=None, edge_layout=None)
 # ``edge_layout`` is the batch's host-precomputed banded layout (the
-# layout-carrying batch contract, DESIGN.md §7); models without a
-# φ1-form edge pathway (linear, tfn) accept and ignore it.
+# layout-carrying batch contract, DESIGN.md §7), which the fused edge
+# kernels walk; linear, with no edge pathway, accepts and ignores it.
+# Models with the virtual-node plug-in return its final state as
+# ``aux["virtual"]``, so the objective adds the MMD term (Eq. 11).
 def _egnn_full(p, cfg, g, axis_name=None, edge_layout=None):
     x, h = egnn.egnn_apply(p, cfg, g, edge_layout=edge_layout)
     return x, {"h": h}
@@ -75,18 +77,26 @@ def _fast_egnn_full(p, cfg, g, axis_name=None, edge_layout=None):
     return x, {"h": h, "virtual": vs}
 
 
+def _with_virtual(aux: dict, vs) -> dict:
+    """``aux`` with the plug-in's final virtual state under ``"virtual"``
+    (where the trainer finds Z for the MMD term), when the model has one."""
+    return aux if vs is None else {**aux, "virtual": vs}
+
+
 def _rf_full(p, cfg, g, axis_name=None, edge_layout=None):
-    return rf.rf_apply(p, cfg, g, axis_name, edge_layout=edge_layout), {}
+    x, vs = rf.rf_apply(p, cfg, g, axis_name, edge_layout=edge_layout)
+    return x, _with_virtual({}, vs)
 
 
 def _schnet_full(p, cfg, g, axis_name=None, edge_layout=None):
-    x, h = schnet.schnet_apply(p, cfg, g, axis_name, edge_layout=edge_layout)
-    return x, {"h": h}
+    x, h, vs = schnet.schnet_apply(p, cfg, g, axis_name,
+                                   edge_layout=edge_layout)
+    return x, _with_virtual({"h": h}, vs)
 
 
 def _tfn_full(p, cfg, g, axis_name=None, edge_layout=None):
-    x, h = tfn.tfn_apply(p, cfg, g, axis_name)
-    return x, {"h": h}
+    x, h, vs = tfn.tfn_apply(p, cfg, g, axis_name, edge_layout=edge_layout)
+    return x, _with_virtual({"h": h}, vs)
 
 
 def _linear_full(p, cfg, g, axis_name=None, edge_layout=None):

@@ -54,7 +54,10 @@ def init_rf(key, cfg: RFConfig):
 
 
 def rf_apply(params, cfg: RFConfig, g: GeometricGraph,
-             axis_name: Optional[str] = None, edge_layout=None) -> Array:
+             axis_name: Optional[str] = None, edge_layout=None
+             ) -> tuple[Array, Optional[VirtualState]]:
+    """Returns (coords (N,3), final virtual state or None without the
+    plug-in)."""
     x = g.x
     n = x.shape[0]
     vs = None
@@ -76,4 +79,4 @@ def rf_apply(params, cfg: RFConfig, g: GeometricGraph,
         if cfg.velocity:
             dx = dx + g.v  # RF integrates the initial velocity directly
         x = x + dx * g.node_mask[:, None]
-    return x
+    return x, vs

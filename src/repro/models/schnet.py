@@ -82,7 +82,10 @@ def init_schnet(key, cfg: SchNetConfig):
 
 def schnet_apply(params, cfg: SchNetConfig, g: GeometricGraph,
                  axis_name: Optional[str] = None,
-                 edge_layout=None) -> tuple[Array, Array]:
+                 edge_layout=None
+                 ) -> tuple[Array, Array, Optional[VirtualState]]:
+    """Returns (coords (N,3), feats (N,hidden), final virtual state or
+    None without the plug-in)."""
     h = mlp(params["embed"], g.h)
     x = g.x
     vs = None
@@ -112,4 +115,4 @@ def schnet_apply(params, cfg: SchNetConfig, g: GeometricGraph,
         if cfg.velocity:
             dx = dx + mlp(lp["phi_v"], h) * g.v
         x = x + dx * g.node_mask[:, None]
-    return x, h
+    return x, h, vs

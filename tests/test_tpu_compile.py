@@ -265,3 +265,107 @@ def test_train_step_op_names_carry_kernel_passes_by_layer(chip):
     assert any("mse_loss" in o for o in op_names)
     assert any(o.startswith("jit(train_step)/adam_update/")
                for o in op_names)
+
+
+def _batched(sharding, batch=2):
+    """Shapes with a leading batch axis on the described chip."""
+    return lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(
+        (batch,) + shape, dt, sharding=sharding)
+
+
+def _layout_shapes(b):
+    """A batch of host banded layouts at Water-3D scale, as shapes."""
+    window, swindow, n_pad = pick_windows(N)
+    be = mp.EDGE_KERNEL_BLOCK_E
+    cap = layout_capacity(DEG * N, n_pad // window, n_pad // swindow, be)
+    return EdgeLayout(b((cap,), jnp.int32), b((cap,), jnp.int32), b((cap,)),
+                      b((cap // be,), jnp.int32), b((cap // be,), jnp.int32),
+                      meta=LayoutMeta(window, swindow, n_pad, be))
+
+
+def _tfn_train_args(sharding, precision):
+    """The FastTFN train step at Water-3D scale and its operands' shapes,
+    as ``Pipeline.train_step`` gets them: a layout-carrying
+    ``GraphBatch``."""
+    from repro.data.loader import GraphBatch
+    from repro.pipeline import build_pipeline
+    from repro.training.trainer import TrainConfig, build_train_step
+
+    pipe = build_pipeline("fast_tfn", jax.random.PRNGKey(0),
+                          train_cfg=TrainConfig(lam_mmd=0.03),
+                          n_layers=4, hidden=HID, h_in=1, n_virtual=3,
+                          s_dim=HID, rbf_cutoff=0.035, use_kernel=True,
+                          precision=precision)
+    b = _batched(sharding)
+    graph = make_graph(jnp.zeros((N, 3)), None, jnp.zeros((N, 1)),
+                       jnp.zeros(DEG * N, jnp.int32),
+                       jnp.zeros(DEG * N, jnp.int32))
+    gb = GraphBatch(jax.tree.map(lambda a: b(a.shape, a.dtype), graph),
+                    b((N, 3)), _layout_shapes(b), b(()))
+    step, _ = build_train_step(pipe.apply_full, pipe.cfg, pipe.train_cfg,
+                               pipe.opt)
+    args = (_shapes(pipe.params, sharding),
+            _shapes(pipe.opt.init(pipe.params), sharding), gb,
+            jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=sharding))
+    return step, args
+
+
+@pytest.mark.parametrize("precision", ["f32", "bf16"])
+def test_fast_tfn_train_step_compiles_fused(chip, precision):
+    """The whole FastTFN train step at Water-3D scale, as the trainer
+    calls it: the fused TFN edge kernel's three passes and the virtual
+    kernel in every layer, the MMD kernel in the objective."""
+    import re
+
+    mp.reset_dispatch_counts()
+    step, args = _tfn_train_args(chip, precision)
+    text = step.lower(*args).compile().as_text()
+    counts = mp.dispatch_counts()
+    assert counts.get("tfn_edge_kernel") == 4 and not counts.get(
+        "tfn_edge_jnp"), counts
+    assert counts.get("virtual_kernel") == 4 and counts.get("mmd_kernel")
+    kernel_calls = set(re.findall(r'op_name="([^"]*/pallas_call)"', text))
+    for k in range(4):
+        for name in ("tfn_edge_fused_fwd", "tfn_edge_bwd_fused_recv",
+                     "tfn_edge_bwd_fused_send"):
+            pat = re.compile(rf"\blayer_{k}\)*/edge_pathway/.*/{name}/")
+            assert any(pat.search(o) for o in kernel_calls), (k, name)
+
+
+def _tfn_edge_step(hid, sharding):
+    """A vmapped value-and-grad of the TFN edge pathway alone at Water-3D
+    scale with host layouts, and its operands' shapes."""
+    b = _batched(sharding)
+    radial = init_mlp(jax.random.PRNGKey(0), [16 + hid, hid, 6])
+
+    def loss(radial, x, h, v, lay):
+        def one(x, h, v, lay):
+            dx, h_agg = ops.tfn_edge_pathway(radial, h, x, v, lay,
+                                             cutoff=0.035, clamp=100.0)
+            return jnp.sum(dx) + jnp.sum(h_agg)
+        return jnp.sum(jax.vmap(one)(x, h, v, lay))
+
+    return (jax.value_and_grad(loss, argnums=(0, 1, 2, 3)),
+            (_shapes(radial, sharding), b((N, 3)), b((N, hid)), b((N, 3)),
+             _layout_shapes(b)))
+
+
+@pytest.mark.parametrize("hid", [384, 448])
+def test_tfn_eligibility_agrees_with_compiler(chip, hid):
+    """Either side of the VMEM budget (f32): what the TFN kernel's model
+    admits compiles, and what it refuses does not; hidden 64 is admitted
+    at Water-3D scale and at 113K."""
+    from repro.kernels.edge_message import VMEM_LIMIT_BYTES
+    from repro.kernels.tfn_edge import tfn_edge_vmem_bytes
+
+    for n in (N, FLUID113K):
+        assert tfn_edge_vmem_bytes(n, HID, 16) <= VMEM_LIMIT_BYTES
+    admitted = tfn_edge_vmem_bytes(N, hid, 16) <= VMEM_LIMIT_BYTES
+    step, args = _tfn_edge_step(hid, chip)
+    try:
+        _compile(step, *args)
+        compiles = True
+    except Exception as e:  # noqa: BLE001
+        assert "vmem" in str(e).lower(), e
+        compiles = False
+    assert admitted == compiles, (hid, admitted)
