@@ -26,6 +26,7 @@ other in ``tests/test_kernels.py`` and ``tests/test_message_passing.py``.
 from __future__ import annotations
 
 import math
+import threading
 from typing import NamedTuple, Optional
 
 import jax
@@ -148,12 +149,19 @@ def _scaled_rel(rel: Array, d2: Array, spec: EdgeSpec) -> Array:
 # 'edge_layout_host' / 'edge_layout_regroup' (kernels.edge_message).
 # Because jit caches traces, counts reflect *traces*, not executions:
 # reset before building a fresh jitted program to observe its dispatch
-# decisions.
+# decisions.  Beside them, the data plane's layout telemetry
+# ('edge_blocks_live' / 'edge_blocks_walked', ``data.layout_cache``):
+# summed over every host layout handed out, the blocks before the live
+# count and the blocks of the static grid; their ratio is the share of
+# the edge kernels' grid steps that do work.  Locked: the stream's worker
+# threads record concurrently.
 DISPATCH_COUNTS: dict[str, int] = {}
+_DISPATCH_LOCK = threading.Lock()
 
 
-def record_dispatch(event: str) -> None:
-    DISPATCH_COUNTS[event] = DISPATCH_COUNTS.get(event, 0) + 1
+def record_dispatch(event: str, n: int = 1) -> None:
+    with _DISPATCH_LOCK:
+        DISPATCH_COUNTS[event] = DISPATCH_COUNTS.get(event, 0) + n
 
 
 def reset_dispatch_counts() -> None:

@@ -205,28 +205,31 @@ def device_banded_layout(snd, rcv, em, *, n_nodes: int,
     rcv = rcv.astype(jnp.int32)
     em = em.astype(jnp.float32)
 
-    band = (rcv // window) * nsw + snd // swindow
-    order = jnp.argsort(band, stable=True)
-    bs = band[order]
-    counts = jnp.zeros((nw * nsw,), jnp.int32).at[bs].add(1)
-    padded = ((counts + block_e - 1) // block_e) * block_e
-    per_w = padded.reshape(nw, nsw).sum(axis=1)
-    padded = (padded.reshape(nw, nsw)
-              .at[:, 0].add(jnp.where(per_w == 0, block_e, 0))
-              .reshape(-1))
-    ends = jnp.cumsum(padded)
-    offs = ends - padded
-    gstart = jnp.cumsum(counts) - counts
-    pos = offs[bs] + (jnp.arange(e, dtype=jnp.int32) - gstart[bs])
-
     cap = layout_capacity(e, nw, nsw, block_e)
     if capacity is not None:
         assert capacity >= cap, (capacity, cap)
         cap = capacity
     n_blocks = cap // block_e
-    out_s = jnp.zeros((cap,), jnp.int32).at[pos].set(snd[order])
-    out_r = jnp.zeros((cap,), jnp.int32).at[pos].set(rcv[order])
-    out_m = jnp.zeros((cap,), jnp.float32).at[pos].set(em[order])
+    # masked slots: a sentinel band past the last, starting at the
+    # capacity, whose scatters drop
+    band = jnp.where(em != 0, (rcv // window) * nsw + snd // swindow,
+                     nw * nsw)
+    order = jnp.argsort(band, stable=True)
+    bs = band[order]
+    counts = jnp.zeros((nw * nsw + 1,), jnp.int32).at[bs].add(1)
+    padded = ((counts[:-1] + block_e - 1) // block_e) * block_e
+    per_w = padded.reshape(nw, nsw).sum(axis=1)
+    padded = (padded.reshape(nw, nsw)
+              .at[:, 0].add(jnp.where(per_w == 0, block_e, 0))
+              .reshape(-1))
+    ends = jnp.cumsum(padded)
+    offs = jnp.append(ends - padded, cap)
+    gstart = jnp.cumsum(counts) - counts
+    pos = offs[bs] + (jnp.arange(e, dtype=jnp.int32) - gstart[bs])
+    put = lambda v, dt: jnp.zeros((cap,), dt).at[pos].set(v, mode="drop")
+    out_s = put(snd[order], jnp.int32)
+    out_r = put(rcv[order], jnp.int32)
+    out_m = put(em[order], jnp.float32)
     bfirst = jnp.arange(n_blocks, dtype=jnp.int32) * block_e
     bid = jnp.searchsorted(ends, bfirst, side="right").astype(jnp.int32)
     bid = jnp.where(bfirst < ends[-1], bid, nw * nsw - 1)

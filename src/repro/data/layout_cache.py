@@ -51,7 +51,9 @@ import numpy as np
 
 from repro.data.radius_graph import BandedCSR, banded_csr_layout
 
-_FORMAT_VERSION = 1
+#: bumped whenever the layout a build produces changes, so that no entry
+#: built by an older rule is served (2: masked slots are placed in no band)
+_FORMAT_VERSION = 2
 
 #: a claim file older than this is a crashed/stalled owner — steal it
 CLAIM_TTL_S = 300.0
@@ -232,6 +234,19 @@ def get_or_build(cache: LayoutCache | None, snd: np.ndarray, rcv: np.ndarray,
     once (the owner may have finished) and otherwise built anyway, with
     the wasted work counted as ``duplicate_builds``.
     """
+    lay = _get_or_build(cache, snd, rcv, n_nodes, edge_mask=edge_mask,
+                        block_e=block_e)
+    from repro.core.message_passing import record_dispatch
+
+    # the edge kernels' live count of this layout (their grid steps that
+    # do work) against its static grid
+    record_dispatch("edge_blocks_live",
+                    int(lay.window_offsets[-1]) // lay.block_e)
+    record_dispatch("edge_blocks_walked", lay.block_rwin.shape[0])
+    return lay
+
+
+def _get_or_build(cache, snd, rcv, n_nodes, *, edge_mask, block_e):
     if cache is None:
         _record("builds")
         return banded_csr_layout(snd, rcv, n_nodes, edge_mask=edge_mask,

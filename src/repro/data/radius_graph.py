@@ -138,7 +138,8 @@ class BandedCSR(NamedTuple):
     The fused edge kernel tiles the node axis into receiver windows of
     ``window`` rows and sender windows of ``swindow`` rows; edges are
     regrouped by the (receiver-window × sender-window) band they live in,
-    each band padded to whole blocks of ``block_e`` edges.  ``senders`` /
+    each band padded to whole blocks of ``block_e`` edges; masked edges
+    are placed in no band.  ``senders`` /
     ``receivers`` / ``edge_mask`` are the regrouped (capacity-padded)
     global edge arrays; ``block_rwin`` / ``block_swin`` give each edge
     block's window coordinates; ``window_offsets`` are per-receiver-window
@@ -185,8 +186,12 @@ def banded_csr_layout(
     snd = np.asarray(snd, np.int32)
     rcv = np.asarray(rcv, np.int32)
 
-    band = (rcv // window) * nsw + snd // swindow
-    order = np.argsort(band, kind="stable")
+    # masked slots go in no band: they sort past the last band and are
+    # dropped, so every block before the last band's end holds a live edge
+    # or zeroes an empty receiver window (DESIGN.md §3.1)
+    live = em != 0
+    band = np.where(live, (rcv // window) * nsw + snd // swindow, nw * nsw)
+    order = np.argsort(band, kind="stable")[:int(live.sum())]
     bs = band[order]
     counts = np.bincount(bs, minlength=nw * nsw).astype(np.int64)
     padded = -(-counts // block_e) * block_e
@@ -197,7 +202,7 @@ def banded_csr_layout(
     ends = np.cumsum(padded)
     offs = ends - padded
     gstart = np.cumsum(counts) - counts
-    pos = (offs[bs] + (np.arange(e) - gstart[bs])).astype(np.int64)
+    pos = (offs[bs] + (np.arange(bs.size) - gstart[bs])).astype(np.int64)
 
     cap = layout_capacity(e, nw, nsw, block_e)
     if capacity is not None:

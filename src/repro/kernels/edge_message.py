@@ -42,6 +42,22 @@ every VMEM buffer by a *node window* instead of N:
     window zeroes it.  They hold packed sums (below), recombined and
     degree-normalised once after the grid.
 
+The live prefix
+---------------
+The grid is static, ``layout_capacity // block_e`` steps, so that one
+program holds any graph of its size; a real layout fills a prefix of it
+(DESIGN.md §3.1).  Masked slots are placed in no band, so every block
+before the last band's end holds a live edge or zeroes an empty receiver
+window, and every block after it is the all-masked capacity tail.  Each
+pass reads the prefix's length, :func:`live_block_count`, as its first
+scalar-prefetch operand and runs its body under ``pl.when(step <
+n_live)``; every index map clamps the step to the last live one
+(:func:`walk_coords`), so a skipped step asks for the blocks already
+resident: no DMA, and no write-back of an output block it never
+initialised.  The sender-major pass sorts the dead blocks after the live
+ones and masks the sender windows no live block visits
+(:func:`sender_walk`).
+
 Eligibility is now a *VMEM budget* (``message_passing.kernel_supported``)
 computed from ``block_e``, the window sizes and the hidden dims — constant
 in N — instead of a node-count ceiling.
@@ -66,13 +82,13 @@ blocks:
     endpoint, plus *all nine weight/bias gradients* (full-resident output
     blocks, zeroed at the first grid step and accumulated across the
     whole sequential grid);
-  * **sender-major pass** — the same blocks walked in
-    ``argsort(block_swin)`` order (a trace-time permutation of the static
-    per-block coordinates, scalar-prefetched like the window ids), so each
-    sender window's blocks form one contiguous run and dL/dx, dL/dh can be
-    accumulated into (swindow, ·) output blocks with the same
-    init-on-first-block discipline.  Sender windows no block touches are
-    masked to zero afterwards.
+  * **sender-major pass** — the same blocks walked in sender-window
+    order (:func:`sender_walk`: a stable argsort of the per-block sender
+    windows, dead blocks last, scalar-prefetched like the window ids), so
+    each sender window's blocks form one contiguous run and dL/dx, dL/dh
+    can be accumulated into (swindow, ·) output blocks with the same
+    init-on-first-block discipline.  Sender windows no live block
+    touches are masked to zero afterwards.
 
 The masked-mean ``inv = 1/max(deg, 1)`` is folded into the per-edge
 upstream cotangents, so neither pass needs a normalisation epilogue.  The
@@ -229,7 +245,10 @@ def layout_capacity(e: int, nw: int, nsw: int, block_e: int) -> int:
     Each nonempty (receiver-window × sender-window) band wastes at most
     ``block_e − 1`` padding slots; each empty receiver window still gets
     one all-masked block so its output block is visited (zeroed) exactly
-    once.  Bands nonempty ≤ min(nw·nsw, e).
+    once.  Bands nonempty ≤ min(nw·nsw, e).  The bound has to hold any
+    graph of ``e`` edges, so it budgets a part-filled block for every
+    band; a layout fills a prefix of it, and the kernels walk only that
+    prefix (:func:`live_block_count`), not the capacity tail.
     """
     used = e + min(nw * nsw, max(e, 1)) * (block_e - 1) + nw * block_e
     return _round_up(used, block_e)
@@ -244,35 +263,40 @@ def banded_layout(snd: Array, rcv: Array, em: Array, *, n_pad: int,
     two agree slot-for-slot (tested in ``tests/test_banded_csr.py``).
 
     Returns ``(snd_loc, rcv_loc, em_b, block_rwin, block_swin, n_blocks)``:
-    window-local endpoint indices in banded order (capacity-padded, masked
-    slots have em=0) plus per-block window coordinates for scalar prefetch.
+    window-local endpoint indices in banded order (capacity-padded; unused
+    slots are 0 with em=0) plus per-block window coordinates for scalar
+    prefetch.  Masked input slots (em=0) are placed in no band.
     ``n_blocks`` is static (from :func:`layout_capacity`).
     """
     e = snd.shape[0]
     nw = n_pad // window
     nsw = n_pad // swindow
     n_bands = nw * nsw
+    cap = layout_capacity(e, nw, nsw, block_e)
+    n_blocks = cap // block_e
     snd = snd.astype(jnp.int32)
     rcv = rcv.astype(jnp.int32)
-    band = (rcv // window) * nsw + snd // swindow  # (E,)
+    # masked slots go to a sentinel band past the last, which starts at the
+    # capacity: their scatters drop, so they are placed in no band
+    band = jnp.where(em != 0, (rcv // window) * nsw + snd // swindow,
+                     n_bands)  # (E,)
     order = jnp.argsort(band, stable=True)
     bs = band[order]
-    counts = jnp.zeros((n_bands,), jnp.int32).at[bs].add(1)
-    padded = ((counts + block_e - 1) // block_e) * block_e
+    counts = jnp.zeros((n_bands + 1,), jnp.int32).at[bs].add(1)
+    padded = ((counts[:n_bands] + block_e - 1) // block_e) * block_e
     # every receiver window gets ≥ 1 block so its output block is zeroed
     per_w = padded.reshape(nw, nsw).sum(axis=1)
     padded = (padded.reshape(nw, nsw)
               .at[:, 0].add(jnp.where(per_w == 0, block_e, 0))
               .reshape(-1))
     ends = jnp.cumsum(padded)
-    offs = ends - padded
+    offs = jnp.append(ends - padded, cap)
     gstart = jnp.cumsum(counts) - counts
     pos = offs[bs] + (jnp.arange(e, dtype=jnp.int32) - gstart[bs])
-    cap = layout_capacity(e, nw, nsw, block_e)
-    n_blocks = cap // block_e
-    snd_loc = jnp.zeros((cap,), jnp.int32).at[pos].set(snd[order] % swindow)
-    rcv_loc = jnp.zeros((cap,), jnp.int32).at[pos].set(rcv[order] % window)
-    em_b = jnp.zeros((cap,), em.dtype).at[pos].set(em[order])
+    put = lambda v, dt: jnp.zeros((cap,), dt).at[pos].set(v, mode="drop")
+    snd_loc = put(snd[order] % swindow, jnp.int32)
+    rcv_loc = put(rcv[order] % window, jnp.int32)
+    em_b = put(em[order], em.dtype)
     bfirst = jnp.arange(n_blocks, dtype=jnp.int32) * block_e
     bid = jnp.searchsorted(ends, bfirst, side="right").astype(jnp.int32)
     # capacity-tail blocks (all-masked) extend the last receiver window's
@@ -281,6 +305,68 @@ def banded_layout(snd: Array, rcv: Array, em: Array, *, n_pad: int,
     block_rwin = bid // nsw
     block_swin = bid % nsw
     return snd_loc, rcv_loc, em_b, block_rwin, block_swin, n_blocks
+
+
+def live_block_count(em_b: Array, block_rwin: Array, block_e: int) -> Array:
+    """Grid steps a pass does work on, (1,) int32: 1 + the index of the
+    last block that holds a live edge or opens its receiver window (whose
+    output block it zeroes).  Every block past it is dead, so skipping it
+    changes no sum.  The banded layouts (:func:`banded_layout` and its
+    numpy and device twins) leave only the capacity tail past it
+    (DESIGN.md §3.1)."""
+    nb = block_rwin.shape[0]
+    holds = jnp.any(em_b.reshape(nb, block_e) != 0, axis=1)
+    opens = jnp.concatenate([jnp.ones((1,), bool),
+                             block_rwin[1:] != block_rwin[:-1]])
+    step = jnp.arange(1, nb + 1, dtype=jnp.int32)
+    return jnp.max(jnp.where(holds | opens, step, 0)).reshape(1)
+
+
+def _live_only(body):
+    """A kernel that takes the live count as its first scalar-prefetch ref
+    and runs ``body`` on the live steps only, given the other refs and the
+    grid step as ``step`` (read outside the ``pl.when``, where the
+    interpreter can lower it)."""
+    def kernel(nl_ref, *refs, **kw):
+        step = pl.program_id(0)
+
+        @pl.when(step < nl_ref[0])
+        def _run():
+            body(*refs, step=step, **kw)
+
+    return kernel
+
+
+def walk_coords(permuted: bool):
+    """(edge block, receiver window, sender window) of a grid step, as
+    functions of the index-map arguments: the step, the live count ``nl``
+    and the scalar-prefetched block coordinates (``rw, sw``, or ``pm, rp,
+    sp`` for the sender-major walk through the permutation ``pm``).  A
+    step past the live count reads as the last live step, so it asks for
+    the blocks already resident: no DMA, and no write-back of an output
+    block it never initialised."""
+    at = lambda i, nl: jnp.minimum(i, nl[0] - 1)
+    if permuted:
+        return (lambda i, nl, pm, rp, sp: pm[at(i, nl)],
+                lambda i, nl, pm, rp, sp: rp[at(i, nl)],
+                lambda i, nl, pm, rp, sp: sp[at(i, nl)])
+    return (lambda i, nl, rw, sw: at(i, nl),
+            lambda i, nl, rw, sw: rw[at(i, nl)],
+            lambda i, nl, rw, sw: sw[at(i, nl)])
+
+
+def sender_walk(block_rwin: Array, block_swin: Array, n_live: Array,
+                nsw: int):
+    """The sender-major pass's block order and what it visits: ``perm``
+    sorts the live blocks by sender window (stable, so each window's
+    blocks keep their receiver-major order) with the dead ones after them,
+    and ``visited`` (nsw,) marks the sender windows a live block gathers
+    from, whose output blocks the pass initialised."""
+    live = jnp.arange(block_swin.shape[0]) < n_live[0]
+    swin = jnp.where(live, block_swin, nsw)
+    perm = jnp.argsort(swin, stable=True).astype(jnp.int32)
+    visited = jnp.zeros((nsw,), bool).at[swin].set(True, mode="drop")
+    return perm, block_rwin[perm], block_swin[perm], visited
 
 
 def _mm(a: Array, b: Array, *, cdt, adt) -> Array:
@@ -390,9 +476,10 @@ def _edge_kernel(
     w1r_ref, w1s_ref, w1d_ref, b1_ref, w2_ref, b2_ref,
     wg1_ref, bg1_ref, wg2_ref,
     acc_ref,
-    *, gate_mode: str, rel_mode: str, clamp: float, compute: str, accum: str,
+    *, step, gate_mode: str, rel_mode: str, clamp: float, compute: str,
+    accum: str,
 ):
-    b = pl.program_id(0)
+    b = step
     rwb = rwin_ref[b]
     rw_prev = jnp.where(b > 0, rwin_ref[jnp.maximum(b - 1, 0)], -1)
     cdt, adt = jnp.dtype(compute), jnp.dtype(accum)
@@ -440,13 +527,28 @@ def _edge_kernel(
                              adt)
 
 
+def _specs(block_e: int, window: int, swindow: int, *, permuted: bool):
+    """BlockSpec factories of a walk (:func:`walk_coords`): edge-id columns
+    (BE, 1) and rows (1, BE), receiver and sender windows of ``k``-lane
+    node operands, and whole arrays."""
+    blk, rwin, swin = walk_coords(permuted)
+    eblk = pl.BlockSpec((block_e, 1), lambda *i: (blk(*i), 0))
+    erow = pl.BlockSpec((1, block_e), lambda *i: (0, blk(*i)))
+    rblk = lambda k: pl.BlockSpec((window, k), lambda *i: (rwin(*i), 0))
+    sblk = lambda k, **kw: pl.BlockSpec((swindow, k),
+                                        lambda *i: (swin(*i), 0), **kw)
+    full = lambda a: pl.BlockSpec(a.shape, lambda *i: (0,) * a.ndim)
+    return eblk, erow, rblk, sblk, full
+
+
 def _resolve_banded(x, h, snd, rcv, em, *, n, block_e, window, swindow,
                     layout, record: str | None):
     """Shared fwd/bwd banding step: host layout or trace-time regroup.
 
     Returns ``(snd_loc, rcv_loc, em_b, block_rwin, block_swin, n_blocks,
-    x, h, n_pad, window, swindow)`` with x/h zero-padded to ``n_pad`` rows
-    and the per-slot endpoints window-localised, all (cap,).  ``record``
+    n_live, x, h, n_pad, window, swindow)`` with x/h zero-padded to
+    ``n_pad`` rows and the per-slot endpoints window-localised, all (cap,);
+    ``n_live`` (1,) is the layout's :func:`live_block_count`.  ``record``
     names the dispatch event to log (None on the backward — the forward
     already recorded the pair's layout provenance, and double counts would
     skew the telemetry the regroup gates assert on).
@@ -491,8 +593,9 @@ def _resolve_banded(x, h, snd, rcv, em, *, n, block_e, window, swindow,
         pad = n_pad - n
         x = jnp.pad(x, ((0, pad), (0, 0)))
         h = jnp.pad(h, ((0, pad), (0, 0)))
-    return (snd_loc, rcv_loc, em_b, block_rwin, block_swin, n_blocks, x, h,
-            n_pad, window, swindow)
+    n_live = live_block_count(em_b, block_rwin, block_e)
+    return (snd_loc, rcv_loc, em_b, block_rwin, block_swin, n_blocks, n_live,
+            x, h, n_pad, window, swindow)
 
 
 @functools.partial(
@@ -544,8 +647,8 @@ def edge_pathway_fused(
     if e == 0:  # empty graph: nothing to reduce (edge-drop p=1.0 story)
         return (jnp.zeros((n, 3), out_dt), jnp.zeros((n, m), out_dt),
                 jnp.zeros((n, 1), out_dt))
-    (snd_loc, rcv_loc, em_b, block_rwin, block_swin, n_blocks, x, h, n_pad,
-     window, swindow) = _resolve_banded(
+    (snd_loc, rcv_loc, em_b, block_rwin, block_swin, n_blocks, n_live, x, h,
+     n_pad, window, swindow) = _resolve_banded(
         x, h, snd, rcv, em, n=n, block_e=block_e, window=window,
         swindow=swindow, layout=layout, record="fwd")
     cdt, adt = prec.compute_dtype, prec.accumulate_dtype
@@ -557,17 +660,13 @@ def edge_pathway_fused(
                                        wg1, bg1, wg2))
     width = m + 1 + (3 if gate_mode != "none" else 0)  # [msg | em | dx]
     lanes = _round_up(pieces * width, LANE)
-    full = lambda a: pl.BlockSpec(a.shape, lambda b, rw, sw: (0,) * a.ndim)
-    eblk = pl.BlockSpec((block_e, 1), lambda b, rw, sw: (b, 0))
-    erow = pl.BlockSpec((1, block_e), lambda b, rw, sw: (0, b))
-    rblk = lambda k: pl.BlockSpec((window, k), lambda b, rw, sw: (rw[b], 0))
-    sblk = lambda k: pl.BlockSpec((swindow, k), lambda b, rw, sw: (sw[b], 0))
-
-    kernel = functools.partial(_edge_kernel, gate_mode=gate_mode,
+    eblk, erow, rblk, sblk, full = _specs(block_e, window, swindow,
+                                          permuted=False)
+    kernel = functools.partial(_live_only(_edge_kernel), gate_mode=gate_mode,
                                rel_mode=rel_mode, clamp=clamp,
                                compute=prec.compute, accum=prec.accumulate)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(n_blocks,),
         in_specs=[
             eblk, eblk, erow, eblk,
@@ -584,7 +683,7 @@ def edge_pathway_fused(
         out_shape=jax.ShapeDtypeStruct((n_pad, lanes), adt),
         interpret=interpret,
         compiler_params=_compiler_params(),
-    )(block_rwin, block_swin, snd_loc[:, None], rcv_loc[:, None],
+    )(n_live, block_rwin, block_swin, snd_loc[:, None], rcv_loc[:, None],
       rcv_loc[None, :], em_b.astype(adt)[:, None], xh, xh, *ws)
     sums = unpack(acc[:n], width, pieces)
     deg = sums[:, m:m + 1]
@@ -674,13 +773,14 @@ def _edge_bwd_r_kernel(
     accr_ref,
     dw1r_ref, dw1s_ref, dw1d_ref, db1_ref, dw2_ref, db2_ref,
     dwg1_ref, dbg1_ref, dwg2_ref,
-    *, gate_mode: str, rel_mode: str, clamp: float, compute: str, accum: str,
+    *, step, gate_mode: str, rel_mode: str, clamp: float, compute: str,
+    accum: str,
 ):
     """Receiver-major backward pass: forward's block order, so receiver
     windows form contiguous runs — accumulates the receiver-endpoint x/h
     gradients per window (packed ``[dh | dx]`` sums) and every weight
     gradient across the whole grid."""
-    b = pl.program_id(0)
+    b = step
     rwb = rwin_ref[b]
     rw_prev = jnp.where(b > 0, rwin_ref[jnp.maximum(b - 1, 0)], -1)
     cdt, adt = jnp.dtype(compute), jnp.dtype(accum)
@@ -724,15 +824,16 @@ def _edge_bwd_s_kernel(
     w1r_ref, w1s_ref, w1d_ref, b1_ref, w2_ref, b2_ref,
     wg1_ref, bg1_ref, wg2_ref,
     accs_ref,
-    *, gate_mode: str, rel_mode: str, clamp: float, compute: str, accum: str,
+    *, step, gate_mode: str, rel_mode: str, clamp: float, compute: str,
+    accum: str,
 ):
-    """Sender-major backward pass: the same blocks in ``argsort(block_swin)``
-    order (``perm`` scalar-prefetched into every index map), so sender
+    """Sender-major backward pass: the same blocks in sender-window order
+    (``perm`` of :func:`sender_walk`, in every index map), so sender
     windows form contiguous runs and the sender-endpoint x/h gradients
     accumulate (packed ``[dh | dx]`` sums) with the standard
     init-on-first-block discipline."""
     del perm_ref, rwp_ref  # consumed by the BlockSpec index maps only
-    j = pl.program_id(0)
+    j = step
     swb = swp_ref[j]
     sw_prev = jnp.where(j > 0, swp_ref[jnp.maximum(j - 1, 0)], -1)
     cdt, adt = jnp.dtype(compute), jnp.dtype(accum)
@@ -797,8 +898,8 @@ def edge_pathway_bwd_fused(
     if e == 0:
         return tuple(jnp.zeros(a.shape, adt) for a in ((x, h) + weights))
     m = w2.shape[1]
-    (snd_loc, rcv_loc, em_b, block_rwin, block_swin, n_blocks, x, h, n_pad,
-     window, swindow) = _resolve_banded(
+    (snd_loc, rcv_loc, em_b, block_rwin, block_swin, n_blocks, n_live, x, h,
+     n_pad, window, swindow) = _resolve_banded(
         x, h, snd, rcv, em, n=n, block_e=block_e, window=window,
         swindow=swindow, layout=layout, record=None)
     snd2, rcv2 = snd_loc[:, None], rcv_loc[:, None]
@@ -824,22 +925,20 @@ def edge_pathway_bwd_fused(
               compute=prec.compute, accum=prec.accumulate)
     f = lambda shape: jax.ShapeDtypeStruct(shape, adt)
 
-    # ---- pass A: receiver-major (dx_r, dh_r, all weight grads) ----------
-    full = lambda a: pl.BlockSpec(a.shape, lambda b, rw, sw: (0,) * a.ndim)
-    eblk = pl.BlockSpec((block_e, 1), lambda b, rw, sw: (b, 0))
-    erow = pl.BlockSpec((1, block_e), lambda b, rw, sw: (0, b))
-    rblk = lambda k: pl.BlockSpec((window, k), lambda b, rw, sw: (rw[b], 0))
     # sender-window blocks are single-buffered in both backward passes:
     # their double buffers are the largest term of the VMEM budget, and
     # the window changes only at band boundaries
-    sblk = lambda k: pl.BlockSpec((swindow, k), lambda b, rw, sw: (sw[b], 0),
-                                  pipeline_mode=pl.Buffered(1))
+    one = dict(pipeline_mode=pl.Buffered(1))
+
+    # ---- pass A: receiver-major (dx_r, dh_r, all weight grads) ----------
+    eblk, erow, rblk, sblk, full = _specs(block_e, window, swindow,
+                                          permuted=False)
     grid_a = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(n_blocks,),
         in_specs=[
             eblk, eblk, erow, eblk,
-            rblk(r_pack.shape[1]), sblk(s_pack.shape[1]),
+            rblk(r_pack.shape[1]), sblk(s_pack.shape[1], **one),
             full(ws[0]), full(ws[1]), full(ws[2]), full(ws[3]), full(ws[4]),
             full(ws[5]), full(ws[6]), full(ws[7]), full(ws[8]),
         ],
@@ -849,53 +948,42 @@ def edge_pathway_bwd_fused(
                    full(ws[8])),
     )
     acc_r, *gws = pl.pallas_call(
-        functools.partial(_edge_bwd_r_kernel, **kw),
+        functools.partial(_live_only(_edge_bwd_r_kernel), **kw),
         grid_spec=grid_a,
         name="edge_pathway_bwd_fused_recv",
         out_shape=(f((n_pad, lanes)),) + tuple(f(a.shape) for a in weights),
         interpret=interpret,
         compiler_params=_compiler_params(),
-    )(block_rwin, block_swin, snd2, rcv2, rcv_loc[None, :], em2,
+    )(n_live, block_rwin, block_swin, snd2, rcv2, rcv_loc[None, :], em2,
       r_pack, s_pack, *ws)
 
     # ---- pass B: sender-major over the block permutation (dx_s, dh_s) ---
-    perm = jnp.argsort(block_swin, stable=True).astype(jnp.int32)
-    rw_p = block_rwin[perm]
-    sw_p = block_swin[perm]
-    full_p = lambda a: pl.BlockSpec(a.shape,
-                                    lambda j, pm, rp, sp: (0,) * a.ndim)
-    eblk_p = pl.BlockSpec((block_e, 1), lambda j, pm, rp, sp: (pm[j], 0))
-    erow_p = pl.BlockSpec((1, block_e), lambda j, pm, rp, sp: (0, pm[j]))
-    rblk_p = lambda k: pl.BlockSpec((window, k),
-                                    lambda j, pm, rp, sp: (rp[j], 0))
-    sblk_p = lambda k: pl.BlockSpec((swindow, k),
-                                    lambda j, pm, rp, sp: (sp[j], 0),
-                                    pipeline_mode=pl.Buffered(1))
+    perm, rw_p, sw_p, visited = sender_walk(block_rwin, block_swin, n_live,
+                                            n_pad // swindow)
+    eblk, erow, rblk, sblk, full = _specs(block_e, window, swindow,
+                                          permuted=True)
     grid_b = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(n_blocks,),
         in_specs=[
-            eblk_p, eblk_p, erow_p, eblk_p,
-            rblk_p(r_pack.shape[1]), sblk_p(s_pack.shape[1]),
-            full_p(ws[0]), full_p(ws[1]), full_p(ws[2]), full_p(ws[3]),
-            full_p(ws[4]), full_p(ws[5]), full_p(ws[6]), full_p(ws[7]),
-            full_p(ws[8]),
+            eblk, eblk, erow, eblk,
+            rblk(r_pack.shape[1]), sblk(s_pack.shape[1], **one),
+            full(ws[0]), full(ws[1]), full(ws[2]), full(ws[3]), full(ws[4]),
+            full(ws[5]), full(ws[6]), full(ws[7]), full(ws[8]),
         ],
-        out_specs=sblk_p(lanes),
+        out_specs=sblk(lanes, **one),
     )
     acc_s = pl.pallas_call(
-        functools.partial(_edge_bwd_s_kernel, **kw),
+        functools.partial(_live_only(_edge_bwd_s_kernel), **kw),
         grid_spec=grid_b,
         name="edge_pathway_bwd_fused_send",
         out_shape=f((n_pad, lanes)),
         interpret=interpret,
         compiler_params=_compiler_params(),
-    )(perm, rw_p, sw_p, snd2, rcv2, snd_loc[None, :], em2,
+    )(n_live, perm, rw_p, sw_p, snd2, rcv2, snd_loc[None, :], em2,
       r_pack, s_pack, *ws)
-    # sender windows no block gathers from are never visited → mask, don't
-    # trust their (uninitialised) output blocks
-    nsw = n_pad // swindow
-    visited = jnp.zeros((nsw,), bool).at[block_swin].set(True)
+    # sender windows no live block gathers from are never visited → mask,
+    # don't trust their (uninitialised) output blocks
     acc_s = jnp.where(jnp.repeat(visited, swindow)[:, None], acc_s, 0.0)
     g = unpack(acc_r[:n], dh + 3, pieces) + unpack(acc_s[:n], dh + 3, pieces)
     return (g[:, dh:], g[:, :dh], *gws)

@@ -58,10 +58,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.edge_message import (EdgeLayout, _compiler_params, _mm,
-                                        _resolve_banded, _round_up,
-                                        _silu_grad, onehot_pieces,
-                                        pick_windows, split_pieces)
+from repro.kernels.edge_message import (EdgeLayout, _compiler_params,
+                                        _live_only, _mm, _resolve_banded,
+                                        _round_up, _silu_grad, onehot_pieces,
+                                        pick_windows, sender_walk,
+                                        split_pieces, walk_coords)
 
 Array = jax.Array
 
@@ -216,9 +217,9 @@ def _edge_terms(xr: list, s: Array, centers, w1rT, w2T, b2, mm, *,
 
 def _tfn_fwd_kernel(rwin_ref, swin_ref, sndr_ref, rcvr_ref, rcvc_ref,
                     emr_ref, r_ref, s_ref, c_ref, w1rT_ref, w2T_ref, b2_ref,
-                    acc_ref, *, hidden: int, gamma: float, clamp: float,
+                    acc_ref, *, step, hidden: int, gamma: float, clamp: float,
                     compute: str, accum: str):
-    b = pl.program_id(0)
+    b = step
     rw_prev = jnp.where(b > 0, rwin_ref[jnp.maximum(b - 1, 0)], -1)
     cdt, adt = jnp.dtype(compute), jnp.dtype(accum)
     pieces = onehot_pieces(cdt)
@@ -285,11 +286,11 @@ def _bwd_common(sndr, rcvr, em, s_win, r_win, centers, w1rT, w1r, w2T, w2,
 def _tfn_bwd_r_kernel(rwin_ref, swin_ref, sndr_ref, rcvr_ref, rcvc_ref,
                       emr_ref, r_ref, s_ref, c_ref, w1rT_ref, w1r_ref,
                       w2T_ref, w2_ref, b2_ref, accr_ref, dw1rT_ref, dw2T_ref,
-                      db2_ref, *, hidden: int, gamma: float, clamp: float,
+                      db2_ref, *, step, hidden: int, gamma: float, clamp: float,
                       compute: str, accum: str):
     """Receiver-major pass: dL/dx_i per receiver window, and the weight
     gradients over the whole grid."""
-    b = pl.program_id(0)
+    b = step
     rw_prev = jnp.where(b > 0, rwin_ref[jnp.maximum(b - 1, 0)], -1)
     cdt, adt = jnp.dtype(compute), jnp.dtype(accum)
     pieces = onehot_pieces(cdt)
@@ -318,13 +319,14 @@ def _tfn_bwd_r_kernel(rwin_ref, swin_ref, sndr_ref, rcvr_ref, rcvc_ref,
 
 def _tfn_bwd_s_kernel(perm_ref, rwp_ref, swp_ref, sndr_ref, rcvr_ref,
                       sndc_ref, emr_ref, r_ref, s_ref, c_ref, w1rT_ref,
-                      w1r_ref, w2T_ref, w2_ref, b2_ref, accs_ref, *,
+                      w1r_ref, w2T_ref, w2_ref, b2_ref, accs_ref, *, step,
                       hidden: int, gamma: float, clamp: float, compute: str,
                       accum: str):
-    """Sender-major pass over the blocks in ``argsort(block_swin)`` order:
-    ``[dL/da_j | dL/dv_j | dL/dx_j]`` per sender window."""
+    """Sender-major pass over the blocks in sender-window order
+    (``edge_message.sender_walk``): ``[dL/da_j | dL/dv_j | dL/dx_j]`` per
+    sender window."""
     del perm_ref, rwp_ref  # consumed by the BlockSpec index maps only
-    j = pl.program_id(0)
+    j = step
     sw_prev = jnp.where(j > 0, swp_ref[jnp.maximum(j - 1, 0)], -1)
     cdt, adt = jnp.dtype(compute), jnp.dtype(accum)
     pieces = onehot_pieces(cdt)
@@ -358,14 +360,15 @@ def _banded(x, a, v, layout: EdgeLayout):
     block_e = layout.senders.shape[0] // n_blocks
     hidden = a.shape[1]
     meta = layout.meta
-    (snd_loc, rcv_loc, em_b, block_rwin, block_swin, n_blocks, x, av, n_pad,
-     window, swindow) = _resolve_banded(
+    (snd_loc, rcv_loc, em_b, block_rwin, block_swin, n_blocks, n_live, x, av,
+     n_pad, window, swindow) = _resolve_banded(
         x, jnp.concatenate([a, v], axis=-1), layout.senders,
         layout.receivers, layout.edge_mask, n=x.shape[0], block_e=block_e,
         window=meta and meta.window, swindow=meta and meta.swindow,
         layout=layout, record=None)
     return dict(snd=snd_loc, rcv=rcv_loc, em=em_b, rwin=block_rwin,
-                swin=block_swin, n_blocks=n_blocks, block_e=block_e, x=x,
+                swin=block_swin, n_blocks=n_blocks, n_live=n_live,
+                block_e=block_e, x=x,
                 a=av[:, :hidden], v=av[:, hidden:], n_pad=n_pad,
                 window=window, swindow=swindow)
 
@@ -401,27 +404,18 @@ def _weights(centers, w1r, w2, b2, cdt) -> tuple:
 def _specs(k, *, permuted: bool):
     """BlockSpec factories over the grid: edge-id rows (1, BE), columns
     (BE, 1), receiver and sender windows of a transposed operand, and whole
-    arrays; on the sender-major pass through the block permutation."""
+    arrays; steps past the live count clamped, and on the sender-major
+    pass through the block permutation (``edge_message.walk_coords``)."""
     be, window, swindow = k["block_e"], k["window"], k["swindow"]
-    if permuted:
-        blk = lambda j, pm, rp, sp: pm[j]
-        rwin = lambda j, pm, rp, sp: rp[j]
-        swin = lambda j, pm, rp, sp: sp[j]
-        zero = lambda j, pm, rp, sp: 0
-    else:
-        blk = lambda b, rw, sw: b
-        rwin = lambda b, rw, sw: rw[b]
-        swin = lambda b, rw, sw: sw[b]
-        zero = lambda b, rw, sw: 0
-    row = pl.BlockSpec((1, be), lambda *i: (zero(*i), blk(*i)))
-    col = pl.BlockSpec((be, 1), lambda *i: (blk(*i), zero(*i)))
-    rblk = lambda h: pl.BlockSpec((h, window), lambda *i: (zero(*i), rwin(*i)))
+    blk, rwin, swin = walk_coords(permuted)
+    row = pl.BlockSpec((1, be), lambda *i: (0, blk(*i)))
+    col = pl.BlockSpec((be, 1), lambda *i: (blk(*i), 0))
+    rblk = lambda h: pl.BlockSpec((h, window), lambda *i: (0, rwin(*i)))
     # sender windows single-buffered on the backward, as in the FastEGNN
     # backward: the window changes only at band boundaries
     sblk = lambda h, **kw: pl.BlockSpec(
-        (h, swindow), lambda *i: (zero(*i), swin(*i)), **kw)
-    full = lambda arr: pl.BlockSpec(arr.shape,
-                                    lambda *i: (zero(*i),) * arr.ndim)
+        (h, swindow), lambda *i: (0, swin(*i)), **kw)
+    full = lambda arr: pl.BlockSpec(arr.shape, lambda *i: (0,) * arr.ndim)
     return row, col, rblk, sblk, full
 
 
@@ -452,11 +446,11 @@ def tfn_edge_fused(x: Array, a: Array, v: Array, layout: EdgeLayout,
     out_rows = _round_up(pieces * GROUP, 2 * SUB)
     row, col, rblk, sblk, full = _specs(k, permuted=False)
     acc = pl.pallas_call(
-        functools.partial(_tfn_fwd_kernel, hidden=hidden,
+        functools.partial(_live_only(_tfn_fwd_kernel), hidden=hidden,
                           gamma=w1r.shape[0] / cutoff, clamp=clamp,
                           compute=prec.compute, accum=prec.accumulate),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(k["n_blocks"],),
+            num_scalar_prefetch=3, grid=(k["n_blocks"],),
             in_specs=[row, row, col, row, rblk(r_pack.shape[0]),
                       sblk(s_pack.shape[0])] + [full(w) for w in ws],
             out_specs=rblk(out_rows)),
@@ -464,8 +458,9 @@ def tfn_edge_fused(x: Array, a: Array, v: Array, layout: EdgeLayout,
         out_shape=jax.ShapeDtypeStruct((out_rows, k["n_pad"]), adt),
         interpret=interpret,
         compiler_params=_compiler_params(),
-    )(k["rwin"], k["swin"], k["snd"][None, :], k["rcv"][None, :],
-      k["rcv"][:, None], k["em"].astype(adt)[None, :], r_pack, s_pack, *ws)
+    )(k["n_live"], k["rwin"], k["swin"], k["snd"][None, :],
+      k["rcv"][None, :], k["rcv"][:, None], k["em"].astype(adt)[None, :],
+      r_pack, s_pack, *ws)
     sums = _unpack_t(acc[:, :n], GROUP, pieces).T  # (n, 8)
     deg = sums[:, 5:6]
     inv = 1.0 / jnp.maximum(deg, 1.0)
@@ -514,9 +509,9 @@ def tfn_edge_bwd_fused(x: Array, a: Array, v: Array, layout: EdgeLayout,
     # ---- pass A: receiver-major (dx_i, weight grads) ---------------------
     row, col, rblk, sblk, full = _specs(k, permuted=False)
     acc_r, gw1rT, gw2T, gb2 = pl.pallas_call(
-        functools.partial(_tfn_bwd_r_kernel, **kw),
+        functools.partial(_live_only(_tfn_bwd_r_kernel), **kw),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(k["n_blocks"],),
+            num_scalar_prefetch=3, grid=(k["n_blocks"],),
             in_specs=[row, row, col, row, rblk(r_pack.shape[0]),
                       sblk(s_pack.shape[0], pipeline_mode=pl.Buffered(1))]
             + [full(w) for w in ws],
@@ -526,16 +521,18 @@ def tfn_edge_bwd_fused(x: Array, a: Array, v: Array, layout: EdgeLayout,
                                                       for w in w_grads),
         interpret=interpret,
         compiler_params=_compiler_params(),
-    )(k["rwin"], k["swin"], sndr, rcvr, k["rcv"][:, None], emr, r_pack,
-      s_pack, *ws)
+    )(k["n_live"], k["rwin"], k["swin"], sndr, rcvr, k["rcv"][:, None], emr,
+      r_pack, s_pack, *ws)
 
     # ---- pass B: sender-major over the block permutation (a_j, v_j, x_j) -
-    perm = jnp.argsort(k["swin"], stable=True).astype(jnp.int32)
+    swindow = k["swindow"]
+    perm, rw_p, sw_p, visited = sender_walk(k["rwin"], k["swin"], k["n_live"],
+                                            k["n_pad"] // swindow)
     row, col, rblk, sblk, full = _specs(k, permuted=True)
     acc_s = pl.pallas_call(
-        functools.partial(_tfn_bwd_s_kernel, **kw),
+        functools.partial(_live_only(_tfn_bwd_s_kernel), **kw),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3, grid=(k["n_blocks"],),
+            num_scalar_prefetch=4, grid=(k["n_blocks"],),
             in_specs=[row, row, col, row, rblk(r_pack.shape[0]),
                       sblk(s_pack.shape[0], pipeline_mode=pl.Buffered(1))]
             + [full(w) for w in ws],
@@ -544,11 +541,9 @@ def tfn_edge_bwd_fused(x: Array, a: Array, v: Array, layout: EdgeLayout,
         out_shape=f((rows_s, k["n_pad"])),
         interpret=interpret,
         compiler_params=_compiler_params(),
-    )(perm, k["rwin"][perm], k["swin"][perm], sndr, rcvr, k["snd"][:, None],
-      emr, r_pack, s_pack, *ws)
-    # sender windows no block gathers from are never visited: mask them
-    swindow = k["swindow"]
-    visited = jnp.zeros((k["n_pad"] // swindow,), bool).at[k["swin"]].set(True)
+    )(k["n_live"], perm, rw_p, sw_p, sndr, rcvr, k["snd"][:, None], emr,
+      r_pack, s_pack, *ws)
+    # sender windows no live block gathers from are never visited: mask them
     acc_s = jnp.where(jnp.repeat(visited, swindow)[None, :], acc_s, 0.0)
     g_s = _unpack_t(acc_s[:, :n], xo + GROUP, pieces).T  # [g_a | g_v | g_x]
     g_r = _unpack_t(acc_r[:, :n], GROUP, pieces).T  # [g_x]

@@ -26,9 +26,20 @@ def _random_edges(n, e, seed=0, masked=True):
 @pytest.mark.parametrize("n,e,block_e", [(100, 400, 32), (1000, 3000, 64),
                                          (8192, 10000, 128)])
 def test_host_layout_matches_trace_layout(n, e, block_e):
-    """The data layer's numpy pass and the kernel's jnp regrouping use the
-    same stable grouping, so they must agree slot-for-slot."""
+    """The data layer's numpy pass, the kernel's jnp regrouping and the
+    rollout's device build use the same stable grouping, so they must
+    agree slot-for-slot.  Masked slots, the random fifth and a tail of
+    ``0 -> 0`` pad slots, are placed in no band: only live edges fill
+    the blocks before the last band's end, every one of those blocks
+    holds one or zeroes an empty receiver window, and the derived live
+    count ends there."""
+    from repro.data.cell_list import device_banded_layout
+    from repro.kernels.edge_message import live_block_count
+
     snd, rcv, em = _random_edges(n, e, seed=n)
+    pad = np.zeros(e // 4, np.int32)
+    snd, rcv = np.concatenate([snd, pad]), np.concatenate([rcv, pad])
+    em = np.concatenate([em, pad.astype(np.float32)])
     host = banded_csr_layout(snd, rcv, n, edge_mask=em, block_e=block_e)
     window, swindow, n_pad = pick_windows(n)
     assert (host.window, host.swindow, host.n_pad) == (window, swindow, n_pad)
@@ -36,15 +47,30 @@ def test_host_layout_matches_trace_layout(n, e, block_e):
     snd_l, rcv_l, em_b, rwin, swin, nb = banded_layout(
         jnp.asarray(snd), jnp.asarray(rcv), jnp.asarray(em),
         n_pad=n_pad, window=window, swindow=swindow, block_e=block_e)
+    dev = device_banded_layout(jnp.asarray(snd), jnp.asarray(rcv),
+                               jnp.asarray(em), n_nodes=n, block_e=block_e)
     assert nb == host.block_rwin.size
-    np.testing.assert_array_equal(np.asarray(rwin), host.block_rwin)
-    np.testing.assert_array_equal(np.asarray(swin), host.block_swin)
-    np.testing.assert_array_equal(np.asarray(em_b), host.edge_mask)
-    live = host.edge_mask > 0
-    np.testing.assert_array_equal(np.asarray(snd_l)[live],
-                                  host.senders[live] % swindow)
-    np.testing.assert_array_equal(np.asarray(rcv_l)[live],
-                                  host.receivers[live] % window)
+    want = (host.edge_mask, host.block_rwin, host.block_swin)
+    for got in ((em_b, rwin, swin),
+                (dev.edge_mask, dev.block_rwin, dev.block_swin)):
+        for a, b in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(a), b)
+    np.testing.assert_array_equal(np.asarray(dev.senders), host.senders)
+    np.testing.assert_array_equal(np.asarray(dev.receivers), host.receivers)
+    np.testing.assert_array_equal(np.asarray(snd_l), host.senders % swindow)
+    np.testing.assert_array_equal(np.asarray(rcv_l), host.receivers % window)
+
+    # masked slots unbanded: the live edges and the blocks before the end
+    end = int(host.window_offsets[-1])
+    assert int((host.edge_mask[:end] > 0).sum()) == int((em > 0).sum())
+    assert not host.edge_mask[end:].any()
+    holds = (host.edge_mask[:end].reshape(-1, block_e) > 0).any(axis=1)
+    opens = np.diff(host.block_rwin[:end // block_e], prepend=-1) != 0
+    assert (holds | opens).all()
+    n_live = live_block_count(jnp.asarray(host.edge_mask),
+                              jnp.asarray(host.block_rwin), block_e)
+    assert n_live.shape == (1,) and n_live.dtype == jnp.int32
+    assert int(n_live[0]) == end // block_e < nb
 
 
 @pytest.mark.parametrize("n,e", [(300, 900), (5000, 20000)])
@@ -75,6 +101,34 @@ def test_layout_invariants(n, e):
     assert L.window_offsets[0] == 0
     assert L.window_offsets[-1] <= L.senders.size
     assert (np.diff(L.window_offsets) >= 0).all()
+
+
+def test_cache_serves_no_layout_of_an_older_format(tmp_path, monkeypatch):
+    """An entry cached under an older ``_FORMAT_VERSION`` (built by an
+    older banding rule) is never served: the layout is built again.  The
+    live-block telemetry counts every layout handed out, built or
+    cached."""
+    from repro.data import layout_cache as lc
+
+    n, e = 600, 2000
+    snd, rcv, em = _random_edges(n, e, seed=5)
+    cache = lc.LayoutCache(tmp_path)
+    version = lc._FORMAT_VERSION
+    with monkeypatch.context() as old:
+        old.setattr(lc, "_FORMAT_VERSION", version - 1)
+        lc.get_or_build(cache, snd, rcv, n, edge_mask=em)
+    lc.reset_cache_stats()
+    mp.reset_dispatch_counts()
+    lay = lc.get_or_build(cache, snd, rcv, n, edge_mask=em)
+    assert lc.cache_stats()["hits"] == 0 and lc.cache_stats()["builds"] == 1
+    again = lc.get_or_build(cache, snd, rcv, n, edge_mask=em)
+    assert lc.cache_stats()["hits"] == 1 and lc.cache_stats()["builds"] == 1
+    for f in ("senders", "edge_mask", "block_rwin", "block_swin"):
+        np.testing.assert_array_equal(getattr(again, f), getattr(lay, f))
+    c = mp.dispatch_counts()
+    live = int(lay.window_offsets[-1]) // lay.block_e
+    assert c["edge_blocks_live"] == 2 * live
+    assert c["edge_blocks_walked"] == 2 * lay.block_rwin.size > 2 * live
 
 
 def test_layout_capacity_bound():

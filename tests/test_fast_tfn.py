@@ -6,6 +6,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+import walkcases
 
 from repro.core import message_passing as mp
 from repro.core.graph import make_graph
@@ -173,6 +174,45 @@ def test_fused_under_vmap_matches_each_graph():
     _, w0 = _value_and_grads(_fused(lays[0], cfg), gs[0], radial)
     _, w1 = _value_and_grads(_fused(lays[1], cfg), gs[1], radial)
     _assert_close(got[0], jax.tree.map(jnp.add, w0[0], w1[0]))
+
+
+@pytest.mark.parametrize("batched", [False, True])
+@pytest.mark.parametrize("case", walkcases.CASES)
+def test_fused_live_walk_bitwise_full_walk(case, batched, walks_agree):
+    """The TFN edge kernel's forward and both backward passes give bit for
+    bit what a walk of the whole static grid gives, on layouts with dead
+    blocks, alone and under vmap over samples whose live counts differ."""
+    from repro.kernels.tfn_edge import tfn_edge_bwd_fused, tfn_edge_fused
+
+    edges, lays, live, n_blocks = walkcases.walk_layouts(
+        case, 2 if batched else 1)
+    assert max(live) < n_blocks, (live, n_blocks)
+    if batched and case == "pads":
+        assert live[0] != live[1], live
+    b, n = len(lays), walkcases.N
+    rng = np.random.default_rng(5)
+    x, v, g_dx = (jnp.asarray(rng.normal(size=(b, n, 3)), jnp.float32)
+                  for _ in range(3))
+    a = jnp.asarray(rng.normal(size=(b, n, HID)), jnp.float32)
+    g_h = jnp.asarray(rng.normal(size=(b, n, 2)), jnp.float32)
+    lay = jax.tree.map(lambda *t: jnp.stack(t), *lays)
+    radial = _radial()
+    ws = (jnp.linspace(0.0, CUTOFF, RBF)[None, :], radial[0]["w"][:RBF],
+          radial[1]["w"], radial[1]["b"][None, :])
+    kw = dict(cutoff=CUTOFF, clamp=0.4, interpret=True)
+
+    def one(x, a, v, lay, g_dx, g_h):
+        out = tfn_edge_fused(x, a, v, lay, *ws, **kw)
+        return out, tfn_edge_bwd_fused(x, a, v, lay, *ws, out[2], g_dx, g_h,
+                                       **kw)
+
+    args = (x, a, v, lay, g_dx, g_h)
+    run = (lambda: jax.vmap(one)(*args)) if batched else \
+        (lambda: one(*jax.tree.map(lambda t: t[0], args)))
+    (dx, _, deg), grads = walks_agree(run)
+    assert np.all(np.isfinite(dx)) and np.all(np.isfinite(grads[0]))
+    em = np.stack([ed[2] for ed in edges])
+    assert float(deg.sum()) == float(em.sum())
 
 
 def _rotation(seed, det=1.0):

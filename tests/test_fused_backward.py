@@ -333,6 +333,32 @@ def test_train_step_dispatch_acceptance():
     assert c.get("edge_layout_host", 0) > 0, c
 
 
+def test_train_step_live_walk_bitwise_full_walk(walks_agree):
+    """A FastEGNN train step over a batch of layout-carrying scenes whose
+    live counts differ (the edge kernels vmapped over the batch): new
+    parameters, optimizer state and metrics bit for bit those of every
+    pass walking its whole static grid."""
+    from repro.data.fluid import generate_fluid_dataset
+    from repro.kernels.edge_message import live_block_count
+    from repro.pipeline import build_pipeline
+    from repro.training.trainer import TrainConfig
+
+    data = generate_fluid_dataset(2, n_particles=300, seed=3)
+    pipe = build_pipeline("fast_egnn", jax.random.PRNGKey(0), use_kernel=True,
+                          train_cfg=TrainConfig(lam_mmd=0.01),
+                          n_layers=2, hidden=16, h_in=1, n_virtual=3, s_dim=8)
+    batch = pipe.make_batches(data, 2, r=0.15)[0]
+    lay = batch.layout
+    be = lay.senders.shape[-1] // lay.block_rwin.shape[-1]
+    live = jax.vmap(live_block_count, (0, 0, None))(lay.edge_mask,
+                                                    lay.block_rwin, be)
+    live = np.asarray(live)[:, 0]
+    assert live[0] != live[1] and live.max() < lay.block_rwin.shape[-1], live
+    opt_state = pipe.opt.init(pipe.params)
+    walks_agree(lambda: pipe.train_step(pipe.params, opt_state, batch,
+                                        jax.random.PRNGKey(1)))
+
+
 def test_loss_scale_grads_invariant():
     """TrainConfig.loss_scale: scaled-then-unscaled training matches the
     unscaled step (static scaling is numerically inert in f32)."""

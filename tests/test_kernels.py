@@ -3,6 +3,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+import walkcases
 
 from repro.core import message_passing as mp
 from repro.core.graph import make_graph
@@ -440,6 +441,50 @@ def test_edge_kernel_multiwindow_fwd_and_vjp_match_ref(gate_mode):
     assert len(got_g) == len(want_g)
     for a, b in zip(got_g, want_g):
         close(a, b)
+
+
+# ------------------------------------------------ live prefix of the walk
+@pytest.mark.parametrize("batched", [False, True])
+@pytest.mark.parametrize("case", walkcases.CASES)
+def test_edge_kernel_live_walk_bitwise_full_walk(case, batched, walks_agree):
+    """The FastEGNN edge kernel's forward and both backward passes give
+    bit for bit what a walk of the whole static grid gives, on layouts
+    with dead blocks, alone and under vmap over samples whose live counts
+    differ."""
+    from repro.kernels.edge_message import edge_pathway_bwd_fused
+
+    spec = _EDGE_SPECS["egnn"]
+    edges, lays, live, n_blocks = walkcases.walk_layouts(
+        case, 2 if batched else 1)
+    if case != "no_edges":
+        assert max(live) < n_blocks, (live, n_blocks)
+    if batched and case == "pads":
+        assert live[0] != live[1], live
+    lp = _edge_params(jax.random.PRNGKey(31), 8, 16, spec)
+    ks = jax.random.split(jax.random.PRNGKey(32), 4)
+    b = len(lays)
+    n = walkcases.N
+    x = jax.random.normal(ks[0], (b, n, 3))
+    h = jax.random.normal(ks[1], (b, n, 8))
+    g_dx = jax.random.normal(ks[2], (b, n, 3))
+    g_mh = jax.random.normal(ks[3], (b, n, 16))
+    snd, rcv, em = (jnp.asarray(np.stack(a)) for a in zip(*edges))
+    lay = jax.tree.map(lambda *a: jnp.stack(a), *lays)
+    kw = dict(gate_mode=spec.gate, rel_mode=spec.rel, clamp=spec.coord_clamp,
+              block_e=walkcases.BE, interpret=True, **walkcases.TILING)
+
+    def one(x, h, snd, rcv, em, lay, g_dx, g_mh):
+        hk, ws = kops.unpack_edge_params(lp, h, spec)
+        out = edge_pathway_fused(x, hk, snd, rcv, em, *ws, layout=lay, **kw)
+        return out, edge_pathway_bwd_fused(x, hk, snd, rcv, em, *ws, out[2],
+                                           g_dx, g_mh, layout=lay, **kw)
+
+    args = (x, h, snd, rcv, em, lay, g_dx, g_mh)
+    run = (lambda: jax.vmap(one)(*args)) if batched else \
+        (lambda: one(*jax.tree.map(lambda a: a[0], args)))
+    (dx, mh, deg), grads = walks_agree(run)
+    assert np.all(np.isfinite(dx)) and np.all(np.isfinite(grads[0]))
+    assert float(deg.sum()) == float(em.sum())
 
 
 @pytest.mark.parametrize("n,c,sigma,block", [(100, 3, 1.5, 64), (1024, 10, 3.0, 256),
