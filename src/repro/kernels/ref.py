@@ -155,23 +155,3 @@ def mmd_cross_ref(x: Array, z: Array, node_mask: Array, sigma: float) -> Array:
     k = jnp.exp(-d2 / (2.0 * sigma * sigma))
     return jnp.sum(k * node_mask[:, None])
 
-
-def swa_attention_ref(q: Array, k: Array, v: Array, window: int | None,
-                      causal: bool = True) -> Array:
-    """Sliding-window (optionally causal) attention oracle.
-
-    q,k,v: (S, H, D) — single batch; window = number of past positions
-    visible (None = unlimited).  softmax over masked logits, scaled by 1/√D.
-    """
-    s, nh, d = q.shape
-    logits = jnp.einsum("qhd,khd->hqk", q, k) / jnp.sqrt(jnp.asarray(d, q.dtype))
-    qi = jnp.arange(s)[:, None]
-    ki = jnp.arange(s)[None, :]
-    mask = jnp.ones((s, s), bool)
-    if causal:
-        mask &= ki <= qi
-    if window is not None:
-        mask &= ki > qi - window
-    logits = jnp.where(mask[None], logits, -1e30)
-    p = jax.nn.softmax(logits, axis=-1)
-    return jnp.einsum("hqk,khd->qhd", p, v)

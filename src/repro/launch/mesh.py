@@ -1,18 +1,9 @@
-"""Production mesh construction (pure function — importing never touches
-jax device state; the dry-run sets XLA_FLAGS *before* calling this)."""
+"""Multi-host start-up (importing never touches jax device state)."""
 from __future__ import annotations
 
 from typing import Optional
 
 import jax
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    """16×16 = 256 chips per pod; 2 pods = 512 chips multi-pod."""
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes,
-                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def init_distributed(coordinator_address: str, num_processes: int,

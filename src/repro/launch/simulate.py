@@ -5,8 +5,7 @@ RolloutService` (DESIGN.md §12): load or synthesise a scene, submit it,
 stream frames as they arrive at rebuild boundaries, and report the
 trajectory statistics plus the service's own metrics snapshot — so the
 single-scene path and the many-concurrent-requests path exercise the
-same admission/batching/program-cache code.  (``launch/serve.py`` is
-the unrelated LM-seed decoder; the GNN service is ``repro.serving``.)
+same admission/batching/program-cache code.
 
   PYTHONPATH=src python -m repro.launch.simulate --n 1024 --steps 100
   PYTHONPATH=src python -m repro.launch.simulate --scene scene.npz \

@@ -1,25 +1,20 @@
-"""Training launcher.
+"""Training launcher: train FastEGNN/DistEGNN on a synthetic dataset —
 
-Two modes:
-  * GNN mode (the paper): train FastEGNN/DistEGNN on a synthetic dataset —
-      python -m repro.launch.train gnn --model fast_egnn --dataset nbody \
-          --epochs 50 --n-virtual 3 --drop-rate 0.75 [--devices 4]
-    Both device counts go through the one pipeline API (DESIGN.md §7):
-    ``build_pipeline(name, key, mesh=...)`` + ``pipe.make_batches`` +
-    ``pipe.fit`` — ``--devices 1`` drives the vmap trainer over
-    layout-carrying GraphBatches, ``--devices > 1`` drives the shard_map
-    DistEGNN path over that many of the devices JAX finds (model pinned to
-    fast_egnn, the paper's Sec. VI architecture) and fails when there are
-    fewer.  A CPU rehearsal sets
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=D`` itself.
-  * LM mode (assigned pool): short real-data-free training run of a reduced
-    architecture —
-      python -m repro.launch.train lm --arch gemma3-12b --steps 100
+    python -m repro.launch.train gnn --model fast_egnn --dataset nbody \
+        --epochs 50 --n-virtual 3 --drop-rate 0.75 [--devices 4]
+
+Both device counts go through the one pipeline API (DESIGN.md §7):
+``build_pipeline(name, key, mesh=...)`` + ``pipe.make_batches`` +
+``pipe.fit`` — ``--devices 1`` drives the vmap trainer over
+layout-carrying GraphBatches, ``--devices > 1`` drives the shard_map
+DistEGNN path over that many of the devices JAX finds (model pinned to
+fast_egnn, the paper's Sec. VI architecture) and fails when there are
+fewer.  A CPU rehearsal sets
+``XLA_FLAGS=--xla_force_host_platform_device_count=D`` itself.
 """
 from __future__ import annotations
 
 import argparse
-import time
 
 
 def gnn_main(args):
@@ -102,43 +97,6 @@ def gnn_main(args):
         print("saved", args.checkpoint)
 
 
-def lm_main(args):
-    import jax
-    import jax.numpy as jnp
-
-    from repro.archs.model import init_arch
-    from repro.configs import get_arch
-    from repro.training.lm import make_train_step
-    from repro.training.optim import Adam, cosine_schedule
-
-    cfg = get_arch(args.arch)
-    if args.reduced:
-        cfg = cfg.reduced()
-    params = init_arch(jax.random.PRNGKey(args.seed), cfg)
-    n_params = sum(x.size for x in jax.tree.leaves(params))
-    print(f"{cfg.name}: {n_params/1e6:.1f}M params")
-    opt = Adam(lr=cosine_schedule(args.lr, 20, args.steps), grad_clip=1.0)
-    st = opt.init(params)
-    step = jax.jit(make_train_step(cfg, opt))
-    key = jax.random.PRNGKey(0)
-    # synthetic structured data: order-k markov streams — enough signal for
-    # the loss to drop well below log(V)
-    tokens = jax.random.randint(key, (args.batch, args.seq + 1), 0, min(cfg.vocab, 512))
-    tokens = tokens.at[:, 1:].set((tokens[:, :-1] * 7 + 13) % min(cfg.vocab, 512))
-    batch = {"tokens": tokens[:, :-1], "labels": tokens[:, 1:]}
-    if cfg.has_encoder:
-        batch["audio"] = jax.random.normal(key, (args.batch, cfg.n_audio_frames, cfg.d_model))
-    if cfg.cross_attn_every:
-        batch["images"] = jax.random.normal(key, (args.batch, cfg.n_image_tokens, cfg.d_model))
-    t0 = time.time()
-    for i in range(args.steps):
-        params, st, m = step(params, st, batch)
-        if i % max(1, args.steps // 20) == 0 or i == args.steps - 1:
-            print(f"step {i:4d}  loss {float(m['loss']):.4f}  nll {float(m['nll']):.4f}",
-                  flush=True)
-    print(f"{args.steps} steps in {time.time()-t0:.1f}s")
-
-
 def main():
     ap = argparse.ArgumentParser()
     sub = ap.add_subparsers(dest="mode", required=True)
@@ -169,20 +127,8 @@ def main():
                    help="host batches buffered ahead of the training step")
     g.add_argument("--workers", type=int, default=4,
                    help="background batch-build threads")
-    li = sub.add_parser("lm")
-    li.add_argument("--arch", required=True)
-    li.add_argument("--steps", type=int, default=100)
-    li.add_argument("--batch", type=int, default=4)
-    li.add_argument("--seq", type=int, default=128)
-    li.add_argument("--lr", type=float, default=3e-4)
-    li.add_argument("--reduced", action="store_true", default=True)
-    li.add_argument("--full", dest="reduced", action="store_false")
-    li.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
-    if args.mode == "gnn":
-        gnn_main(args)
-    else:
-        lm_main(args)
+    gnn_main(args)
 
 
 if __name__ == "__main__":

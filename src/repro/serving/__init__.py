@@ -4,9 +4,6 @@ Request queue + capacity-bucket admission, dynamic same-bucket
 batching, a bounded compiled-program cache, streaming per-chunk
 responses, and serving metrics — layered on
 :class:`~repro.rollout.engine.BatchedRolloutEngine`.
-
-Not to be confused with ``launch/serve.py`` (the LM-seed decoder):
-the GNN rollout service is this package.
 """
 from repro.serving.batcher import (DEFAULT_NODE_BUCKETS, AdmissionError,
                                    BucketKey, DynamicBatcher, PendingRequest,

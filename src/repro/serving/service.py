@@ -15,10 +15,6 @@ boundary, long before the horizon completes — or just block on
 This module deliberately never imports ``repro.pipeline`` — it only
 duck-types the pipeline (``predict_fn``, ``params``, ``cfg.use_kernel``),
 so ``pipeline.py`` can in turn import the serving LRU without a cycle.
-
-Note: ``launch/serve.py`` is the *unrelated* LM-seed decoder that
-predates this subsystem — the GNN rollout service lives here, under
-``repro.serving``.
 """
 from __future__ import annotations
 

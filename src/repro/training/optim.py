@@ -1,8 +1,6 @@
 """Pure-JAX optimizers (no optax): Adam/AdamW with grad clipping + schedules.
 
-State is a plain pytree so it shards with the parameters under pjit (the
-ZeRO-style sharding in ``distributed/sharding.py`` applies the same
-PartitionSpec to ``m``/``v`` as to the parameter itself).
+State is a plain pytree, so ``m``/``v`` take the parameters' shardings.
 """
 from __future__ import annotations
 

@@ -1,17 +1,22 @@
 """Property tests: E(3)/SO(3) equivariance of every geometric model
-(Proposition IV.1) and permutation invariance of the virtual state."""
+(Proposition IV.1), permutation equivariance of every model and
+permutation invariance of the virtual state — on the jnp path and on the
+fused path."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from hypcompat import given, settings, st
+from modelcases import (HIN, MODELS, SO3_ONLY, VIRTUAL, assert_path_taken,
+                        forward, model_paths)
 
 from repro.core.equivariant import apply_e3, random_orthogonal, random_rotation
 from repro.core.graph import make_graph
 from repro.core.mmd import mmd_loss
-from repro.models.registry import make_model
 
-N, E, HIN = 18, 50, 2
+N, E = 18, 50
+# MPNN reads raw coordinates as features: not equivariant, by design
+GEOMETRIC = sorted(n for n in MODELS if n != "mpnn")
 
 
 def _graph(seed=0):
@@ -26,64 +31,77 @@ def _graph(seed=0):
     )
 
 
-MODELS = {
-    "linear": {},
-    "egnn": dict(h_in=HIN, n_layers=2, hidden=16),
-    "fast_egnn": dict(h_in=HIN, n_layers=2, hidden=16, n_virtual=3, s_dim=8),
-    "rf": dict(n_layers=2, hidden=16),
-    "fast_rf": dict(n_layers=2, hidden=16, n_virtual=2),
-    "schnet": dict(h_in=HIN, n_layers=2, hidden=16),
-    "fast_schnet": dict(h_in=HIN, n_layers=2, hidden=16, n_virtual=2, s_dim=8),
-    "tfn": dict(h_in=HIN, n_layers=2, hidden=16),
-    "fast_tfn": dict(h_in=HIN, n_layers=2, hidden=16, n_virtual=2, s_dim=8),
-}
-# TFN's cross-product path is chiral: SO(3) only (like the paper's TFN).
-SO3_ONLY = {"tfn", "fast_tfn"}
+def _permuted(g, perm):
+    """``g`` with its real nodes reordered by ``perm`` and its edges
+    relabelled to match."""
+    inv = jnp.argsort(perm)
+    return g._replace(x=g.x[perm], v=g.v[perm], h=g.h[perm],
+                      senders=inv[g.senders], receivers=inv[g.receivers])
 
 
-@pytest.mark.parametrize("name", sorted(MODELS))
+def _assert_equivariant(got, want):
+    """Within 2e-3, and within 1e-4 of the largest entry."""
+    got, want = np.asarray(got), np.asarray(want)
+    np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-3)
+    scale = float(np.max(np.abs(got))) + 1e-6
+    np.testing.assert_allclose(want / scale, got / scale, rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("name,path", model_paths(GEOMETRIC))
 @given(seed=st.integers(0, 100))
 @settings(max_examples=8, deadline=None)
-def test_e3_equivariance(name, seed):
+def test_e3_equivariance(name, path, seed):
     g = _graph(0)
-    cfg, params, apply_full = make_model(name, jax.random.PRNGKey(1), **MODELS[name])
+    assert_path_taken(name, path, g)
     kk = jax.random.PRNGKey(seed)
     rot = random_rotation(kk) if name in SO3_ONLY else random_orthogonal(kk)
     t = jax.random.normal(jax.random.fold_in(kk, 1), (3,)) * 3.0
 
-    x1, _ = apply_full(params, cfg, g)
-    g2 = g._replace(x=apply_e3(g.x, rot, t), v=g.v @ rot)
-    x2, _ = apply_full(params, cfg, g2)
-    np.testing.assert_allclose(np.asarray(x2), np.asarray(apply_e3(x1, rot, t)),
-                               rtol=2e-3, atol=2e-3)
+    x1, _ = forward(name, path, g)
+    x2, _ = forward(name, path, g._replace(x=apply_e3(g.x, rot, t), v=g.v @ rot))
+    _assert_equivariant(x2, apply_e3(x1, rot, t))
 
 
+@pytest.mark.parametrize("name,path", model_paths())
+def test_node_permutation_equivariance(name, path):
+    """Permuting the real nodes, with the edges relabelled to match,
+    permutes the predicted coordinates and features the same way."""
+    g = _graph(0)
+    perm = jax.random.permutation(jax.random.PRNGKey(3), N)
+    gp = _permuted(g, perm)
+    assert_path_taken(name, path, gp)
+    x1, aux1 = forward(name, path, g)
+    xp, auxp = forward(name, path, gp)
+    np.testing.assert_allclose(np.asarray(xp), np.asarray(x1[perm]),
+                               rtol=2e-4, atol=1e-4)
+    if "h" in aux1:
+        np.testing.assert_allclose(np.asarray(auxp["h"]),
+                                   np.asarray(aux1["h"][perm]),
+                                   rtol=2e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("name,path", model_paths(VIRTUAL))
 @given(seed=st.integers(0, 1000))
 @settings(max_examples=10, deadline=None)
-def test_virtual_state_equivariant_and_perm_invariant(seed):
+def test_virtual_state_equivariant_and_perm_invariant(name, path, seed):
     """Prop IV.1: Z is E(3)-equivariant AND permutation-invariant w.r.t. X."""
     g = _graph(0)
-    cfg, params, apply_full = make_model(
-        "fast_egnn", jax.random.PRNGKey(1), h_in=HIN, n_layers=2, hidden=16,
-        n_virtual=3, s_dim=8)
-    _, aux1 = apply_full(params, cfg, g)
+    assert_path_taken(name, path, g)
+    x1, aux1 = forward(name, path, g)
     kk = jax.random.PRNGKey(seed)
-    rot = random_orthogonal(kk)
+    rot = random_rotation(kk) if name in SO3_ONLY else random_orthogonal(kk)
     t = jax.random.normal(jax.random.fold_in(kk, 1), (3,))
-    _, aux2 = apply_full(params, cfg, g._replace(x=apply_e3(g.x, rot, t), v=g.v @ rot))
+    _, aux2 = forward(name, path,
+                      g._replace(x=apply_e3(g.x, rot, t), v=g.v @ rot))
     np.testing.assert_allclose(np.asarray(aux2["virtual"].z),
                                np.asarray(apply_e3(aux1["virtual"].z, rot, t)),
                                rtol=2e-3, atol=2e-3)
     # permutation of real nodes leaves Z unchanged
     perm = jax.random.permutation(kk, N)
-    inv = jnp.argsort(perm)
-    gp = g._replace(x=g.x[perm], v=g.v[perm], h=g.h[perm],
-                    senders=inv[g.senders], receivers=inv[g.receivers])
-    xp, auxp = apply_full(params, cfg, gp)
+    xp, auxp = forward(name, path, _permuted(g, perm))
     np.testing.assert_allclose(np.asarray(auxp["virtual"].z),
                                np.asarray(aux1["virtual"].z), rtol=2e-3, atol=2e-3)
     # ... while X' is permutation-equivariant
-    x1, _ = apply_full(params, cfg, g)
     np.testing.assert_allclose(np.asarray(xp), np.asarray(x1[perm]),
                                rtol=2e-3, atol=2e-3)
 

@@ -15,7 +15,6 @@ from repro.kernels import ops as kops
 from repro.kernels import ref
 from repro.kernels.edge_message import edge_pathway_fused
 from repro.kernels.mmd_rbf import mmd_cross_sum
-from repro.kernels.swa_attention import swa_attention
 from repro.kernels.virtual_message import virtual_pathway_fused
 
 
@@ -497,29 +496,6 @@ def test_mmd_kernel(n, c, sigma, block):
     got = mmd_cross_sum(x, z, mask, sigma=sigma, block_n=block, interpret=True)
     want = ref.mmd_cross_ref(x, z, mask, sigma)
     np.testing.assert_allclose(float(got), float(want), rtol=1e-5)
-
-
-@pytest.mark.parametrize("s,h,d,window,causal,bq", [
-    (128, 2, 32, None, True, 64),
-    (256, 2, 64, 64, True, 128),
-    (256, 4, 32, 32, True, 32),
-    (128, 1, 64, None, False, 128),
-    (512, 2, 64, 100, True, 128),
-])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_swa_attention_kernel(s, h, d, window, causal, bq, dtype):
-    ks = jax.random.split(jax.random.PRNGKey(s + h), 3)
-    q = jax.random.normal(ks[0], (h, s, d), dtype)
-    k = jax.random.normal(ks[1], (h, s, d), dtype)
-    v = jax.random.normal(ks[2], (h, s, d), dtype)
-    got = swa_attention(q, k, v, causal=causal, window=window,
-                        block_q=bq, block_k=bq, interpret=True)
-    want = ref.swa_attention_ref(
-        q.astype(jnp.float32).transpose(1, 0, 2),
-        k.astype(jnp.float32).transpose(1, 0, 2),
-        v.astype(jnp.float32).transpose(1, 0, 2), window, causal).transpose(1, 0, 2)
-    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want),
-                               **_tol(dtype))
 
 
 def test_mmd_loss_kernel_matches_core():

@@ -6,13 +6,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from modelcases import HIN, MODELS
 
 from repro.core import message_passing as mp
 from repro.core.graph import make_graph
 from repro.data.radius_graph import pad_edges, sort_edges_by_receiver
 from repro.models.registry import REGISTRY, make_model
 
-N, E, HIN = 18, 50, 2
+N, E = 18, 50
 
 
 def _graph(seed=0, csr=False):
@@ -127,34 +128,20 @@ def test_edge_attr_only_consumed_by_sized_phi1():
 
 
 # ------------------------------------------------- registry-wide parity
-_OVERRIDES = {
-    "linear": {},
-    "mpnn": dict(h_in=HIN, n_layers=2, hidden=16),
-    "egnn": dict(h_in=HIN, n_layers=2, hidden=16),
-    "fast_egnn": dict(h_in=HIN, n_layers=2, hidden=16, n_virtual=3, s_dim=8),
-    "rf": dict(n_layers=2, hidden=16),
-    "fast_rf": dict(n_layers=2, hidden=16, n_virtual=2),
-    "schnet": dict(h_in=HIN, n_layers=2, hidden=16),
-    "fast_schnet": dict(h_in=HIN, n_layers=2, hidden=16, n_virtual=2, s_dim=8),
-    "tfn": dict(h_in=HIN, n_layers=2, hidden=16),
-    "fast_tfn": dict(h_in=HIN, n_layers=2, hidden=16, n_virtual=2, s_dim=8),
-}
-
-
 def test_registry_covers_overrides():
-    assert set(_OVERRIDES) == set(REGISTRY)
+    assert set(MODELS) == set(REGISTRY)
 
 
-@pytest.mark.parametrize("name", sorted(_OVERRIDES))
+@pytest.mark.parametrize("name", sorted(MODELS))
 def test_registry_kernel_parity(name):
     """Every registry entry: the jnp substrate and the Pallas pathways
     produce identical predictions from identical seeds (spec composition
     guarantees init is unaffected by use_kernel)."""
     g = _graph(0, csr=True)
     cfg_j, params_j, apply_j = make_model(name, jax.random.PRNGKey(1),
-                                          **_OVERRIDES[name])
+                                          **MODELS[name])
     cfg_k, params_k, apply_k = make_model(name, jax.random.PRNGKey(1),
-                                          use_kernel=True, **_OVERRIDES[name])
+                                          use_kernel=True, **MODELS[name])
     # seed parity: identical parameter trees
     jax.tree.map(lambda a, b: np.testing.assert_array_equal(
         np.asarray(a), np.asarray(b)), params_j, params_k)
@@ -168,9 +155,9 @@ def test_registry_kernel_parity(name):
 def test_registry_kernel_grad_parity(name):
     g = _graph(0, csr=True)
     cfg_j, params, apply_j = make_model(name, jax.random.PRNGKey(1),
-                                        **_OVERRIDES[name])
+                                        **MODELS[name])
     cfg_k, _, apply_k = make_model(name, jax.random.PRNGKey(1),
-                                   use_kernel=True, **_OVERRIDES[name])
+                                   use_kernel=True, **MODELS[name])
     tgt = g.x + 0.1
     loss_j = lambda p: jnp.mean((apply_j(p, cfg_j, g)[0] - tgt) ** 2)
     loss_k = lambda p: jnp.mean((apply_k(p, cfg_k, g)[0] - tgt) ** 2)

@@ -125,32 +125,6 @@ def test_radius_graph_infinite_is_fully_connected():
     assert np.all(snd != rcv)
 
 
-# ----------------------------------------------------------- sharding rules
-def test_param_shardings_cover_all_archs():
-    """Every arch's full-size param tree gets a valid NamedSharding from the
-    name-based rules (eval_shape only — no allocation, no compile)."""
-    os.environ.setdefault("XLA_FLAGS", "")
-    from repro.archs.model import init_arch
-    from repro.configs import _ARCH_IDS, get_arch
-    from repro.distributed.sharding import param_shardings
-
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
-    for aid in _ARCH_IDS:
-        cfg = get_arch(aid)
-        sds = jax.eval_shape(lambda k, c=cfg: init_arch(k, c),
-                             jax.ShapeDtypeStruct((2,), jnp.uint32))
-        shard = param_shardings(sds, mesh)
-        n_leaves = len(jax.tree.leaves(sds))
-        assert len(jax.tree.leaves(shard,
-                                   is_leaf=lambda x: hasattr(x, "spec"))) == n_leaves
-        # every spec's non-None axes must index an existing mesh axis
-        for s in jax.tree.leaves(shard, is_leaf=lambda x: hasattr(x, "spec")):
-            for ax in s.spec:
-                axes = ax if isinstance(ax, tuple) else (ax,)
-                for a in axes:
-                    assert a is None or a in mesh.shape
-
-
 # -------------------------------------------------------------- claims parser
 def test_claims_check_parser(tmp_path):
     from benchmarks.claims_check import parse

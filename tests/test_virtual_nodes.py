@@ -2,6 +2,8 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
+from modelcases import HIN, assert_path_taken, forward, model_paths
 
 from repro.core.graph import make_graph
 from repro.core.mmd import mmd_loss, rbf_kernel
@@ -69,33 +71,38 @@ def test_aggregate_from_sums_equals_aggregate():
     np.testing.assert_allclose(np.asarray(a.s), np.asarray(b.s), rtol=1e-6)
 
 
-def test_padding_invariance_full_model():
+@pytest.mark.parametrize("name,path", model_paths())
+def test_padding_invariance_full_model(name, path):
     """Padded nodes/edges must not change real outputs (SPMD static shapes)."""
-    cfg = FastEGNNConfig(n_layers=2, hidden=16, h_in=2, n_virtual=3, s_dim=8)
-    params = init_fast_egnn(jax.random.PRNGKey(0), cfg)
     ks = jax.random.split(jax.random.PRNGKey(5), 5)
     n, e = 15, 40
     x = jax.random.normal(ks[0], (n, 3))
     v = jax.random.normal(ks[1], (n, 3))
-    h = jax.random.normal(ks[2], (n, 2))
+    h = jax.random.normal(ks[2], (n, HIN))
     snd = jax.random.randint(ks[3], (e,), 0, n)
     rcv = jax.random.randint(ks[4], (e,), 0, n)
     g = make_graph(x, v, h, snd, rcv)
-    x1, _, vs1 = fast_egnn_apply(params, cfg, g)
+    x1, aux1 = forward(name, path, g)
 
     pad_n, pad_e = 7, 13
     gp = make_graph(
         jnp.concatenate([x, jnp.ones((pad_n, 3)) * 9.0]),
         jnp.concatenate([v, jnp.zeros((pad_n, 3))]),
-        jnp.concatenate([h, jnp.zeros((pad_n, 2))]),
+        jnp.concatenate([h, jnp.zeros((pad_n, HIN))]),
         jnp.concatenate([snd, jnp.zeros(pad_e, jnp.int32)]),
         jnp.concatenate([rcv, jnp.zeros(pad_e, jnp.int32)]),
         node_mask=jnp.concatenate([jnp.ones(n), jnp.zeros(pad_n)]),
         edge_mask=jnp.concatenate([jnp.ones(e), jnp.zeros(pad_e)]),
     )
-    x2, _, vs2 = fast_egnn_apply(params, cfg, gp)
+    assert_path_taken(name, path, gp)
+    x2, aux2 = forward(name, path, gp)
     np.testing.assert_allclose(np.asarray(x2[:n]), np.asarray(x1), rtol=2e-4, atol=1e-4)
-    np.testing.assert_allclose(np.asarray(vs2.z), np.asarray(vs1.z), rtol=2e-4, atol=1e-4)
+    if "h" in aux1:
+        np.testing.assert_allclose(np.asarray(aux2["h"][:n]), np.asarray(aux1["h"]),
+                                   rtol=2e-4, atol=1e-4)
+    if "virtual" in aux1:
+        np.testing.assert_allclose(np.asarray(aux2["virtual"].z),
+                                   np.asarray(aux1["virtual"].z), rtol=2e-4, atol=1e-4)
 
 
 def test_mmd_terms_signs():
